@@ -181,6 +181,9 @@ def _worker(quick: bool) -> dict:
 
 def run(quick: bool = False) -> dict:
     env = dict(os.environ)
+    # A CPU virtual-device simulation; the chip's four-device path is
+    # `chip_smoke.py --chips 4`.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={_WORKER_DEVICES}")
     cmd = [sys.executable, "-m", "benchmarks.distributed_bench", "--worker"]

@@ -266,13 +266,10 @@ class JnpSampler(_BaseSampler):
 class PallasSampler(_BaseSampler):
     """The fused Pallas score+Gumbel-max kernel (interpret mode on CPU)."""
 
-    def __init__(self, token_block: int = 256):
-        self.token_block = token_block
-
     def sweep(self, cfg, state, corpus, key):
         from repro.kernels.lda_gibbs import ops as kops
 
-        return kops.sweep(cfg, state, corpus, key, self.token_block)
+        return kops.sweep(cfg, state, corpus, key)
 
 
 @register_backend("distributed", SamplerCapabilities(device_kind="pod"))
@@ -309,11 +306,10 @@ class DistributedSampler(_BaseSampler):
 
     def _mesh(self):
         if self.mesh is None:
-            # Production axis names (launch.mesh), all devices on data: the
-            # old flat ("data",) default made lazily-built meshes
-            # incompatible with every production PartitionSpec.
-            self.mesh = jax.make_mesh(
-                (jax.device_count(), 1), ("data", "model"))
+            from repro.launch.mesh import make_data_mesh
+
+            # Production axis names (launch.mesh), all devices on data.
+            self.mesh = make_data_mesh()
         return self.mesh
 
     def _sweep_fn(self, cfg: LDAConfig):
@@ -333,10 +329,9 @@ class DistributedSampler(_BaseSampler):
     def sweep(self, cfg, state, corpus, key):
         real = decode_state(cfg, state)
         fn = self._sweep_fn(cfg)
-        with self._mesh():
-            z, n_dt, n_wt, n_t = fn(
-                corpus.docs, corpus.words, real.z, corpus.weights,
-                real.n_dt, real.n_wt, key)
+        z, n_dt, n_wt, n_t = fn(
+            corpus.docs, corpus.words, real.z, corpus.weights,
+            real.n_dt, real.n_wt, key)
         return encode_state(
             cfg, LDAState(z=z, n_dt=n_dt, n_wt=n_wt, n_t=n_t))
 
@@ -363,13 +358,12 @@ class PServerSampler(_BaseSampler):
     """
 
     def __init__(self, mesh=None, block: int = 4096, staleness: int = 1,
-                 local: str = "auto", cap=None, mh_steps: int = 4,
-                 token_block: int = 256):
+                 local: str = "auto", cap=None, mh_steps: int = 4):
         from repro.pserver.sampler import PServerFit
 
         self._fit = PServerFit(
             mesh=mesh, block=block, staleness=staleness, local=local,
-            cap=cap, mh_steps=mh_steps, token_block=token_block)
+            cap=cap, mh_steps=mh_steps)
         self.staleness = staleness
 
     def sweep(self, cfg, state, corpus, key):
@@ -406,13 +400,11 @@ class AliasSampler(_BaseSampler):
     sweeps of all M models scanned under one jit (`core.alias.run_many`).
     """
 
-    def __init__(self, mh_steps: int = 4, path: str = "auto",
-                 token_block: int = 256):
+    def __init__(self, mh_steps: int = 4, path: str = "auto"):
         if path not in ("auto", "jnp", "pallas"):
             raise ValueError(f"unknown alias path {path!r}")
         self.mh_steps = mh_steps
         self.path = path
-        self.token_block = token_block
 
     def _path(self) -> str:
         if self.path != "auto":
@@ -423,8 +415,7 @@ class AliasSampler(_BaseSampler):
         if self._path() == "pallas":
             from repro.kernels.alias_mh import ops as kops
 
-            return kops.mh_sweep(
-                cfg, state, corpus, key, self.mh_steps, self.token_block)
+            return kops.mh_sweep(cfg, state, corpus, key, self.mh_steps)
         from repro.core import alias
 
         real = decode_state(cfg, state)
@@ -446,7 +437,7 @@ class AliasSampler(_BaseSampler):
             states = batch_lib.init_many(cfg, corpora, subs)
         return alias.run_many(
             cfg, states, corpora, keys, num_sweeps, self.mh_steps,
-            self.token_block, self._path())
+            self._path())
 
 
 @register_backend(
@@ -516,13 +507,11 @@ class BatchedSampler(_BaseSampler):
     `backend="batched"` is valid anywhere a backend name is accepted.
     """
 
-    def __init__(self, path: str = "auto", block: int = 4096,
-                 token_block: int = 256):
+    def __init__(self, path: str = "auto", block: int = 4096):
         if path not in ("auto", "jnp", "pallas"):
             raise ValueError(f"unknown batched path {path!r}")
         self.path = path
         self.block = block
-        self.token_block = token_block
 
     def _path(self) -> str:
         if self.path != "auto":
@@ -534,8 +523,7 @@ class BatchedSampler(_BaseSampler):
         from repro.core import batch
 
         return batch.sweep_batch(
-            cfg, states, corpora, keys, self.block, self.token_block,
-            self._path())
+            cfg, states, corpora, keys, self.block, self._path())
 
     def run_many(self, cfg, corpora, keys, num_sweeps, states=None):
         """Batched multi-sweep fit/refit: cold when `states` is None."""
@@ -543,7 +531,7 @@ class BatchedSampler(_BaseSampler):
 
         return batch.fit_many(
             cfg, corpora, keys, num_sweeps, states=states, block=self.block,
-            token_block=self.token_block, path=self._path())
+            path=self._path())
 
     def _stack1(self, tree):
         return jax.tree_util.tree_map(lambda x: x[None], tree)

@@ -202,15 +202,14 @@ def mh_sweep(
 # -- batched multi-model sweeps (the `serving.batch_engine` layout) ---------
 
 
-def _sweep_batch(cfg, states, corpora, keys, mh_steps, token_block, path):
+def _sweep_batch(cfg, states, corpora, keys, mh_steps, path):
     """One alias sweep over M stacked models (stored units in and out):
     the model-grid fused kernel on the "pallas" path, the vmapped oracle
     otherwise. Mirrors `core.batch._sweep_batch`."""
     if path == "pallas":
         from repro.kernels.alias_mh import ops as kops
 
-        return kops.mh_sweep_many(
-            cfg, states, corpora, keys, mh_steps, token_block)
+        return kops.mh_sweep_many(cfg, states, corpora, keys, mh_steps)
     from repro.core import codec
 
     def one(st, co, k):
@@ -220,7 +219,7 @@ def _sweep_batch(cfg, states, corpora, keys, mh_steps, token_block, path):
     return jax.vmap(one)(states, corpora, keys)
 
 
-@partial(jax.jit, static_argnums=(0, 4, 5, 6, 7))
+@partial(jax.jit, static_argnums=(0, 4, 5, 6))
 def run_many(
     cfg: LDAConfig,
     states: LDAState,  # stacked warm states (stored units)
@@ -228,7 +227,6 @@ def run_many(
     keys: jax.Array,  # (M, 2) one key per model
     num_sweeps: int,
     mh_steps: int = 4,
-    token_block: int = 256,
     path: str = "jnp",
 ) -> LDAState:
     """`num_sweeps` alias sweeps over all M stacked models under one jit
@@ -245,7 +243,7 @@ def run_many(
 
     def body(carry, ks):
         return _sweep_batch(
-            cfg, carry, corpora, ks, mh_steps, token_block, path), None
+            cfg, carry, corpora, ks, mh_steps, path), None
 
     states, _ = jax.lax.scan(body, states, sweep_keys)
     return states

@@ -126,32 +126,31 @@ def unstack_states(
 # -- batched sweeps -----------------------------------------------------------
 
 
-def _sweep_batch(cfg, states, corpora, keys, block, token_block, path):
+def _sweep_batch(cfg, states, corpora, keys, block, path):
     if path == "pallas":
         from repro.kernels.lda_gibbs import ops as kops
 
-        return kops.sweep_many(cfg, states, corpora, keys, token_block)
+        return kops.sweep_many(cfg, states, corpora, keys)
     return jax.vmap(
         lambda st, co, k: gibbs.sweep(cfg, st, co, k, block)
     )(states, corpora, keys)
 
 
-@partial(jax.jit, static_argnums=(0, 4, 5, 6))
+@partial(jax.jit, static_argnums=(0, 4, 5))
 def sweep_batch(
     cfg: LDAConfig,
     states: LDAState,
     corpora: Corpus,
     keys: jax.Array,  # (M, 2)
     block: int = 4096,
-    token_block: int = 256,
     path: str = "jnp",
 ) -> LDAState:
     """One full sweep over M stacked models; model i consumes keys[i]
     exactly as the single-model `gibbs.sweep`/kernel sweep would."""
-    return _sweep_batch(cfg, states, corpora, keys, block, token_block, path)
+    return _sweep_batch(cfg, states, corpora, keys, block, path)
 
 
-@partial(jax.jit, static_argnums=(0, 4, 5, 6, 7))
+@partial(jax.jit, static_argnums=(0, 4, 5, 6))
 def run_many(
     cfg: LDAConfig,
     states: LDAState,  # stacked warm states (stored units)
@@ -159,7 +158,6 @@ def run_many(
     keys: jax.Array,  # (M, 2) one key per model
     num_sweeps: int,
     block: int = 4096,
-    token_block: int = 256,
     path: str = "jnp",
 ) -> LDAState:
     """`num_sweeps` full sweeps over all M stacked models under one jit.
@@ -174,7 +172,7 @@ def run_many(
 
     def body(carry, ks):
         return _sweep_batch(
-            cfg, carry, corpora, ks, block, token_block, path), None
+            cfg, carry, corpora, ks, block, path), None
 
     states, _ = jax.lax.scan(body, states, sweep_keys)
     return states
@@ -196,7 +194,6 @@ def fit_many(
     num_sweeps: int,
     states: Optional[LDAState] = None,
     block: int = 4096,
-    token_block: int = 256,
     path: str = "jnp",
 ) -> LDAState:
     """Cold (or warm, with `states`) batched fit of M stacked models.
@@ -209,4 +206,4 @@ def fit_many(
         keys, subs = pairs[:, 0], pairs[:, 1]
         states = init_many(cfg, corpora, subs)
     return run_many(
-        cfg, states, corpora, keys, num_sweeps, block, token_block, path)
+        cfg, states, corpora, keys, num_sweeps, block, path)

@@ -33,37 +33,23 @@ last shard's tail is padding (zero-weight tokens, empty n_dt rows) and
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # 0.4.x: experimental API
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.core.gibbs import resample_block
 from repro.core.types import LDAConfig
-
-# The replication-check kwarg was renamed check_rep -> check_vma; detect by
-# signature rather than import location (intermediate versions mix the two).
-_CHECK_KW = ("check_vma"
-             if "check_vma" in inspect.signature(_shard_map).parameters
-             else "check_rep")
+from repro.launch.mesh import auto_axes
 
 
 def make_shard_map(fn, mesh, in_specs, out_specs):
-    """Version-portable `shard_map` (replication checks off: every program
-    here produces replicated outputs by explicit psum)."""
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: False})
-
-
-# Backwards-compatible alias (pre-pserver internal name).
-_make_shard_map = make_shard_map
+    """`jax.shard_map` over `mesh` with Auto axes (a caller's Explicit
+    mesh is converted, so the program's gathers and outputs need no
+    out-shardings) and replication checks off: every program here
+    produces replicated outputs by explicit psum."""
+    return jax.shard_map(fn, mesh=auto_axes(mesh), in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def local_sweep(cfg, docs, words, z, wts, n_dt, n_wt, n_t, key, block):
@@ -93,9 +79,6 @@ def local_sweep(cfg, docs, words, z, wts, n_dt, n_wt, n_t, key, block):
         return resample_block(cfg, d, w, zz, wt, n_dt, n_wt, n_t, g)
 
     return jax.lax.map(body, (d_b, w_b, z_b, wt_b, keys)).reshape(-1)[:n]
-
-
-_local_sweep = local_sweep  # backwards-compatible alias
 
 
 def partition_by_doc(num_docs: int, docs: np.ndarray, n_shards: int):

@@ -233,7 +233,8 @@ def fake_quantize_rows(x, bits: int):
 def quantize_rows_jnp(x, bits: int):
     """jnp twin of `quantize_rows` (codes stay *unpacked* uint8 for bits=4
     — nibble packing happens at the kernel boundary via
-    `pack_nibbles_jnp` so gathers can index full-width rows)."""
+    `kernels.lda_gibbs.kernel.pack_halves` so gathers can index
+    full-width rows)."""
     import jax.numpy as jnp
 
     levels = _levels(bits)
@@ -242,29 +243,3 @@ def quantize_rows_jnp(x, bits: int):
     safe = jnp.where(scales > 0, scales, 1.0)[..., None]
     codes = jnp.clip(jnp.round(xx / safe), 0, levels).astype(jnp.uint8)
     return codes, scales
-
-
-def pack_nibbles_jnp(codes):
-    """jnp twin of `pack_nibbles` ((..., K) codes -> (..., ceil(K/2)))."""
-    import jax.numpy as jnp
-
-    k = codes.shape[-1]
-    if k % 2:
-        pad = [(0, 0)] * (codes.ndim - 1) + [(0, 1)]
-        codes = jnp.pad(codes, pad)
-    low = codes[..., 0::2]
-    high = codes[..., 1::2]
-    return (low | (high << 4)).astype(jnp.uint8)
-
-
-def unpack_nibbles_jnp(packed, k: int):
-    """jnp twin of `unpack_nibbles` — also valid *inside* a Pallas tile
-    body (shifts, masks, stack, reshape are all Mosaic-lowerable), which
-    is what lets the fused kernels read int4-packed rows directly."""
-    import jax.numpy as jnp
-
-    low = packed & 0x0F
-    high = packed >> 4
-    out = jnp.stack([low, high], axis=-1).reshape(
-        packed.shape[:-1] + (-1,))
-    return out[..., :k]

@@ -51,6 +51,11 @@ def generate(spec: SyntheticSpec) -> SyntheticCorpus:
     for t in range(k):
         phi[t, t * block : (t + 1) * block] += 0.95 / block
     phi /= phi.sum(1, keepdims=True)
+    # Per-topic inverse CDFs exactly as `Generator.choice(v, p=phi[t])`
+    # builds them, so drawing one uniform per token and searching its
+    # topic's CDF reproduces the per-token `choice` stream bit for bit.
+    cdf = phi.cumsum(1)
+    cdf /= cdf[:, -1:]
 
     n_neg = max(1, int(k * spec.negative_topic_frac))
     neg_topics = np.arange(k - n_neg, k)  # last topics are negative-only
@@ -75,7 +80,11 @@ def generate(spec: SyntheticSpec) -> SyntheticCorpus:
         n_tok = max(5, int(rng.poisson(spec.mean_tokens)))
         if is_relevant:
             zs = rng.choice(k, size=n_tok, p=theta)
-            toks = np.array([rng.choice(v, p=phi[t]) for t in zs], np.int32)
+            u = rng.random(n_tok)
+            toks = np.empty(n_tok, np.int32)
+            for t in np.unique(zs):
+                at = zs == t
+                toks[at] = cdf[t].searchsorted(u[at], side="right")
         else:
             toks = rng.integers(0, v, n_tok).astype(np.int32)  # off-topic noise
 

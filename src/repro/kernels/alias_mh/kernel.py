@@ -30,30 +30,41 @@ in-kernel: int32 count rows are scaled by 2^-(w_bits+1) before scoring.
 Per-token topic lookups inside a tile use a branch-free masked-iota
 reduction over the K lanes (TPU-friendly; no dynamic lane gather).
 
-Grid: (num_token_blocks,). VMEM per step with TB=256, K=1024: 6 (TB, K)
-tiles (rows_d, rows_w, word/doc thresh + alias) + 3 (S, TB) random strips
-≈ 6.3 MB.
+Layout is `lda_gibbs`'s: per-token vectors are lane-dense (1, N) arrays
+with (1, TB) blocks, the random strips (S, N) with (S, TB) blocks, and
+the totals (1, K); the wrappers take 1-D vectors and reshape at the
+boundary.
+
+Grid: (num_token_blocks,). TB is sized by K (`kernels.tiling`) for 6
+(TB, K) tiles (rows_d, rows_w, word/doc thresh + alias), double-buffered:
+TB=1024 at K=128, TB=128 at K=1024. Callers may pass any N; the wrappers
+pad the token axis with weight-0 tokens, which keep their assignment.
 
 The batched multi-model variant (`alias_mh_blocked_batched`) adds a leading
 *model grid dimension* exactly like `lda_gibbs`: M stacked product models
 share one `pallas_call` with grid (M, num_token_blocks), each token block's
-BlockSpec indexing its own model's rows, tables, totals and noise, so the
-fused batch launch is exactly M independent single-model sweeps.
+BlockSpec (model axis squeezed) indexing its own model's rows, tables,
+totals and noise, so the fused batch launch is exactly M independent
+single-model sweeps.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
+from repro.kernels.tiling import pad_tokens
+
 
 def _mh_tile(
     rows_d,  # (TB, K) gathered doc-topic count rows
     rows_w,  # (TB, K) gathered word-topic count rows
-    tot,  # (K,) topic totals
+    tot,  # (1, K) topic totals
     thresh_w,  # (TB, K) gathered word-table alias thresholds
     alias_w,  # (TB, K) gathered word-table alias targets
     thresh_d,  # (TB, K) gathered doc-table alias thresholds
@@ -95,7 +106,7 @@ def _mh_tile(
         sub = jnp.where((zt == z0) & (w > 0.0), w, 0.0)
         ndt = jnp.maximum(take(rows_d, zt) - sub, 0.0)
         nwt = jnp.maximum(take(rows_w, zt) - sub, 0.0)
-        nt = jnp.maximum(take(tot[None, :], zt) - sub, 1e-9)
+        nt = jnp.maximum(take(tot, zt) - sub, 1e-9)
         return (jnp.log(ndt + alpha) + jnp.log(nwt + beta)
                 - jnp.log(nt + beta_bar))
 
@@ -141,7 +152,9 @@ def _alias_mh_kernel(
     beta_bar: float,
     w_bits: int | None,
 ):
-    z_out_ref[...] = _mh_tile(
+    # The batched kernel's squeezed model axis makes its refs look exactly
+    # like these, so one body serves both grids.
+    z_out_ref[0] = _mh_tile(
         rows_d_ref[...],
         rows_w_ref[...],
         tot_ref[...],
@@ -149,8 +162,8 @@ def _alias_mh_kernel(
         alias_w_ref[...],
         thresh_d_ref[...],
         alias_d_ref[...],
-        z_ref[...],
-        w_ref[...],
+        z_ref[0],
+        w_ref[0],
         j_ref[...],
         up_ref[...],
         ua_ref[...],
@@ -161,46 +174,21 @@ def _alias_mh_kernel(
     )
 
 
-def _alias_mh_kernel_batched(
-    rows_d_ref,
-    rows_w_ref,
-    tot_ref,
-    thresh_w_ref,
-    alias_w_ref,
-    thresh_d_ref,
-    alias_d_ref,
-    z_ref,
-    w_ref,
-    j_ref,
-    up_ref,
-    ua_ref,
-    z_out_ref,
-    *,
-    alpha: float,
-    beta: float,
-    beta_bar: float,
-    w_bits: int | None,
-):
-    # Block shapes carry a leading model dim of 1: this grid step's token
-    # block indexes *its own model's* rows, tables, totals and noise.
-    z_out_ref[0] = _mh_tile(
-        rows_d_ref[0],
-        rows_w_ref[0],
-        tot_ref[0],
-        thresh_w_ref[0],
-        alias_w_ref[0],
-        thresh_d_ref[0],
-        alias_d_ref[0],
-        z_ref[0],
-        w_ref[0],
-        j_ref[0],
-        up_ref[0],
-        ua_ref[0],
-        alpha=alpha,
-        beta=beta,
-        beta_bar=beta_bar,
-        w_bits=w_bits,
-    )
+def _tile_args(rows, tot, z, weights, rnd, npad: int, lead: int):
+    """Token-pad every operand to `npad` and give the 1-D vectors and the
+    totals their lane-dense (1, N) / (1, K) layout. `rows` are the six
+    (.., N, K) tiles, `rnd` the three (.., S, N) random strips, and `lead`
+    the number of leading model axes (0 or 1). The accept uniforms pad
+    with 1 (log 1 = 0, so padding never NaNs the tile)."""
+    rows = [pad_tokens(r, npad, lead) for r in rows]
+    z = jnp.expand_dims(pad_tokens(z, npad, lead), lead)
+    weights = jnp.expand_dims(pad_tokens(weights, npad, lead, 0.0), lead)
+    j, up, ua = rnd
+    rnd = (pad_tokens(j, npad, lead + 1),
+           pad_tokens(up, npad, lead + 1, 0.0),
+           pad_tokens(ua, npad, lead + 1, 1.0))
+    return (*rows[:2], jnp.expand_dims(tot, lead), *rows[2:], z, weights,
+            *rnd)
 
 
 def alias_mh_blocked(
@@ -220,34 +208,34 @@ def alias_mh_blocked(
     alpha: float,
     beta: float,
     beta_bar: float,
+    interpret: bool,
     w_bits: int | None = None,
-    token_block: int = 256,
-    interpret: bool = True,
+    token_block: Optional[int] = None,
 ) -> jax.Array:
     """Tiled pallas_call over token blocks: all S MH rounds fused per tile.
 
-    N must be a multiple of token_block and K a multiple of 128 (caller
-    pads)."""
+    K must be a multiple of 128 (caller pads); N is padded here to a
+    multiple of the token tile."""
     n, k = rows_d.shape
     s = j_prop.shape[0]
-    assert n % token_block == 0, (n, token_block)
     assert k % 128 == 0, k
-    grid = (n // token_block,)
+    tb = tiling.resolve(token_block, k, 24 * k)
+    npad = -(-n // tb) * tb
 
     kern = functools.partial(
         _alias_mh_kernel, alpha=alpha, beta=beta, beta_bar=beta_bar,
         w_bits=w_bits,
     )
-    row_spec = pl.BlockSpec((token_block, k), lambda i: (i, 0))
-    tok_spec = pl.BlockSpec((token_block,), lambda i: (i,))
-    rnd_spec = pl.BlockSpec((s, token_block), lambda i: (0, i))
-    return pl.pallas_call(
+    row_spec = pl.BlockSpec((tb, k), lambda i: (i, 0))
+    tok_spec = pl.BlockSpec((1, tb), lambda i: (0, i))
+    rnd_spec = pl.BlockSpec((s, tb), lambda i: (0, i))
+    out = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(npad // tb,),
         in_specs=[
             row_spec,  # rows_d
             row_spec,  # rows_w
-            pl.BlockSpec((k,), lambda _i: (0,)),
+            pl.BlockSpec((1, k), lambda _i: (0, 0)),
             row_spec,  # thresh_w
             row_spec,  # alias_w
             row_spec,  # thresh_d
@@ -259,11 +247,12 @@ def alias_mh_blocked(
             rnd_spec,  # u_acc
         ],
         out_specs=tok_spec,
-        out_shape=jax.ShapeDtypeStruct((n,), z.dtype),
+        out_shape=jax.ShapeDtypeStruct((1, npad), z.dtype),
         interpret=interpret,
         name="alias_mh_sweep",
-    )(rows_d, rows_w, tot, thresh_w, alias_w, thresh_d, alias_d, z,
-      weights, j_prop, u_prop, u_acc)
+    )(*_tile_args((rows_d, rows_w, thresh_w, alias_w, thresh_d, alias_d),
+                  tot, z, weights, (j_prop, u_prop, u_acc), npad, 0))
+    return out[0, :n]
 
 
 def alias_mh_blocked_batched(
@@ -283,9 +272,9 @@ def alias_mh_blocked_batched(
     alpha: float,
     beta: float,
     beta_bar: float,
+    interpret: bool,
     w_bits: int | None = None,
-    token_block: int = 256,
-    interpret: bool = True,
+    token_block: Optional[int] = None,
 ) -> jax.Array:
     """One kernel launch over M stacked models: grid (M, N // token_block).
 
@@ -297,24 +286,24 @@ def alias_mh_blocked_batched(
     """
     m, n, k = rows_d.shape
     s = j_prop.shape[1]
-    assert n % token_block == 0, (n, token_block)
     assert k % 128 == 0, k
-    grid = (m, n // token_block)
+    tb = tiling.resolve(token_block, k, 24 * k)
+    npad = -(-n // tb) * tb
 
     kern = functools.partial(
-        _alias_mh_kernel_batched, alpha=alpha, beta=beta, beta_bar=beta_bar,
+        _alias_mh_kernel, alpha=alpha, beta=beta, beta_bar=beta_bar,
         w_bits=w_bits,
     )
-    row_spec = pl.BlockSpec((1, token_block, k), lambda j, i: (j, i, 0))
-    tok_spec = pl.BlockSpec((1, token_block), lambda j, i: (j, i))
-    rnd_spec = pl.BlockSpec((1, s, token_block), lambda j, i: (j, 0, i))
-    return pl.pallas_call(
+    row_spec = pl.BlockSpec((None, tb, k), lambda j, i: (j, i, 0))
+    tok_spec = pl.BlockSpec((None, 1, tb), lambda j, i: (j, 0, i))
+    rnd_spec = pl.BlockSpec((None, s, tb), lambda j, i: (j, 0, i))
+    out = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(m, npad // tb),
         in_specs=[
             row_spec,  # rows_d
             row_spec,  # rows_w
-            pl.BlockSpec((1, k), lambda j, _i: (j, 0)),
+            pl.BlockSpec((None, 1, k), lambda j, _i: (j, 0, 0)),
             row_spec,  # thresh_w
             row_spec,  # alias_w
             row_spec,  # thresh_d
@@ -326,8 +315,9 @@ def alias_mh_blocked_batched(
             rnd_spec,  # u_acc
         ],
         out_specs=tok_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), z.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, 1, npad), z.dtype),
         interpret=interpret,
         name="alias_mh_sweep_batched",
-    )(rows_d, rows_w, tot, thresh_w, alias_w, thresh_d, alias_d, z,
-      weights, j_prop, u_prop, u_acc)
+    )(*_tile_args((rows_d, rows_w, thresh_w, alias_w, thresh_d, alias_d),
+                  tot, z, weights, (j_prop, u_prop, u_acc), npad, 1))
+    return out[:, 0, :n]

@@ -7,7 +7,8 @@ alias tables are built outside by the parallel prefix-sum builder
 (`core.alias.build_alias_tables` on the decoded counts), count/table rows
 are gathered (XLA gather — efficient on TPU), the kernel fuses the cycle
 proposal draws plus all `mh_steps` MH rounds per VMEM tile, and counts are
-rebuilt outside. On CPU the kernel body runs in interpret mode.
+rebuilt outside. The kernel is compiled by Mosaic on a TPU; on the CPU
+backend its body runs in interpret mode.
 
 Randomness is precomputed as (S, N) matrices with **exactly** the key
 discipline of `core.alias.mh_sweep` (per-round key -> split 3 -> bucket
@@ -53,14 +54,13 @@ def _draws(key: jax.Array, n: int, k: int, mh_steps: int):
     return jnp.stack(js), jnp.stack(ups), jnp.stack(uas)
 
 
-@partial(jax.jit, static_argnums=(0, 4, 5))
+@partial(jax.jit, static_argnums=(0, 4))
 def mh_resample(
     cfg: LDAConfig,
     state: LDAState,
     corpus: Corpus,
     key: jax.Array,
     mh_steps: int = 4,
-    token_block: int = 256,
 ) -> jax.Array:
     """One fused proposal+MH pass; returns new z (counts rebuilt by
     caller). `state` is in stored units (int32 fixed point when
@@ -78,7 +78,6 @@ def mh_resample(
     n = corpus.num_tokens
     k = cfg.num_topics
     kp = -(-k // 128) * 128  # lane-pad K to 128
-    npad = -(-n // token_block) * token_block
 
     # Stale proposal tables (word + doc cycles): built once per sweep from
     # the decoded counts by the parallel prefix-sum builder, then gathered
@@ -108,62 +107,51 @@ def mh_resample(
 
     j_prop, u_prop, u_acc = _draws(key, n, k, mh_steps)
 
-    def pad2(x, fill=0):
-        return jnp.pad(
-            x, ((0, npad - n), (0, kp - k)), constant_values=fill)
+    def padk(x, fill=0):
+        return jnp.pad(x, ((0, 0), (0, kp - k)), constant_values=fill)
 
-    def pad1(x, fill=0):
-        return jnp.pad(x, (0, npad - n), constant_values=fill)
-
-    def pad_s(x, fill=0):
-        return jnp.pad(x, ((0, 0), (0, npad - n)), constant_values=fill)
-
-    z_new = alias_mh_blocked(
-        pad2(rows_d),
-        pad2(rows_w),
+    return alias_mh_blocked(
+        padk(rows_d),
+        padk(rows_w),
         jnp.pad(n_t, (0, kp - k)),
-        pad2(thresh_w_rows, 0.0),
-        pad2(alias_w_rows),
-        pad2(thresh_d_rows, 0.0),
-        pad2(alias_d_rows),
-        pad1(state.z),
-        pad1(corpus.weights, 0.0),
-        pad_s(j_prop),
-        pad_s(u_prop, 0.0),
-        pad_s(u_acc, 1.0),  # log(1) = 0: padding never NaNs the tile
+        padk(thresh_w_rows, 0.0),
+        padk(alias_w_rows),
+        padk(thresh_d_rows, 0.0),
+        padk(alias_d_rows),
+        state.z,
+        corpus.weights,
+        j_prop,
+        u_prop,
+        u_acc,
         alpha=cfg.alpha,
         beta=cfg.beta,
         beta_bar=cfg.beta_bar,
         w_bits=kernel_w_bits,
-        token_block=token_block,
         interpret=_interpret(),
     )
-    return z_new[:n]
 
 
-@partial(jax.jit, static_argnums=(0, 4, 5))
+@partial(jax.jit, static_argnums=(0, 4))
 def mh_sweep(
     cfg: LDAConfig,
     state: LDAState,
     corpus: Corpus,
     key: jax.Array,
     mh_steps: int = 4,
-    token_block: int = 256,
 ) -> LDAState:
     """Full kernel-path AliasLDA sweep (fused MH + count rebuild), stored
     units in and out."""
-    z_new = mh_resample(cfg, state, corpus, key, mh_steps, token_block)
+    z_new = mh_resample(cfg, state, corpus, key, mh_steps)
     return codec.rebuild_state(cfg, corpus, z_new)
 
 
-@partial(jax.jit, static_argnums=(0, 4, 5))
+@partial(jax.jit, static_argnums=(0, 4))
 def mh_sweep_many(
     cfg: LDAConfig,
     states: LDAState,  # stacked: z (M, N), n_dt (M, D, K), n_wt (M, V, K)
     corpora: Corpus,  # stacked: docs/words/weights (M, N)
     keys: jax.Array,  # (M, 2) one PRNG key per model
     mh_steps: int = 4,
-    token_block: int = 256,
 ) -> LDAState:
     """One fused AliasLDA sweep over M stacked models (single launch).
 
@@ -173,10 +161,9 @@ def mh_sweep_many(
     proposal+MH rounds for all M models, and counts are rebuilt per model
     by a vmapped scatter-add — bit-exact M independent single-model sweeps.
     """
-    m, n = corpora.docs.shape
+    n = corpora.docs.shape[1]
     k = cfg.num_topics
     kp = -(-k // 128) * 128
-    npad = -(-n // token_block) * token_block
 
     thresh_w, alias_w = alias_core.build_alias_tables(
         codec.decode_array(cfg, states.n_wt) + cfg.beta)  # (M, V, K)
@@ -192,36 +179,28 @@ def mh_sweep_many(
     j_prop, u_prop, u_acc = jax.vmap(
         lambda kk: _draws(kk, n, k, mh_steps))(keys)  # (M, S, N) each
 
-    def pad3(x, fill=0):
+    def padk(x, fill=0):
         return jnp.pad(
-            x, ((0, 0), (0, npad - n), (0, kp - k)), constant_values=fill)
-
-    def pad2(x, fill=0):
-        return jnp.pad(x, ((0, 0), (0, npad - n)), constant_values=fill)
-
-    def pad_s(x, fill=0):
-        return jnp.pad(
-            x, ((0, 0), (0, 0), (0, npad - n)), constant_values=fill)
+            x, ((0, 0), (0, 0), (0, kp - k)), constant_values=fill)
 
     z_new = alias_mh_blocked_batched(
-        pad3(rows_d),
-        pad3(rows_w),
+        padk(rows_d),
+        padk(rows_w),
         jnp.pad(states.n_t, ((0, 0), (0, kp - k))),
-        pad3(thresh_w_rows, 0.0),
-        pad3(alias_w_rows),
-        pad3(thresh_d_rows, 0.0),
-        pad3(alias_d_rows),
-        pad2(states.z),
-        pad2(corpora.weights, 0.0),
-        pad_s(j_prop),
-        pad_s(u_prop, 0.0),
-        pad_s(u_acc, 1.0),
+        padk(thresh_w_rows, 0.0),
+        padk(alias_w_rows),
+        padk(thresh_d_rows, 0.0),
+        padk(alias_d_rows),
+        states.z,
+        corpora.weights,
+        j_prop,
+        u_prop,
+        u_acc,
         alpha=cfg.alpha,
         beta=cfg.beta,
         beta_bar=cfg.beta_bar,
         w_bits=cfg.w_bits,
-        token_block=token_block,
         interpret=_interpret(),
-    )[:, :n]
+    )
     return jax.vmap(lambda co, z: codec.rebuild_state(cfg, co, z))(
         corpora, z_new)

@@ -112,7 +112,7 @@ def chunk_scan_pallas(
     include_current: bool,
     chunk: int = 64,
     s0: jax.Array | None = None,  # (B, H, dk, dv)
-    interpret: bool = True,
+    interpret: bool,
 ):
     b, s, h, dk = k.shape
     dv = v.shape[-1]

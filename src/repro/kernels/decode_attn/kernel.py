@@ -107,7 +107,7 @@ def decode_attention_pallas(
     ring: bool = False,
     cap: float = 0.0,
     kv_block: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, s, hkv, hd = k_cache.shape
     hq = q.shape[1]

@@ -16,26 +16,39 @@ TB·4B out) instead of 3× that with materialized logits.
 Fixed-point counts (paper §4.3 approximate weighting, w_bits) are handled
 in-kernel: int32 rows are scaled by 2^-(w_bits+1) before scoring.
 
-Grid: (num_token_blocks,). VMEM per step with TB=256, K=1024:
-3 f32/i32 tiles (rows_d, rows_w, gumbel) + broadcast totals ≈ 3.3 MB.
+Layout. Per-token vectors (z, weights, row scales, the output) enter the
+kernel as lane-dense (1, N) arrays with (1, TB) blocks, and the topic
+totals as (1, K): XLA tiles a 1-D operand by its whole length (T(1024))
+while Mosaic tiles a 1-D block by the block, so 1-D operands are refused
+by the v5e compiler unless TB happens to equal XLA's tile. The wrappers
+take 1-D vectors and reshape at the boundary.
+
+Grid: (num_token_blocks,). TB is sized by K (`kernels.tiling`): 3 (TB, K)
+f32 tiles double-buffered plus the tile body's temporaries stay under the
+VMEM budget — TB=1024 at K=128, TB=256 at K=1024. Callers may pass any N;
+the wrappers pad the token axis to a multiple of TB with weight-0 tokens,
+which keep their assignment.
 
 The batched multi-model variant (`gibbs_resample_blocked_batched`) adds a
 leading *model grid dimension*: M stacked product models share one
 `pallas_call` with grid (M, num_token_blocks), and each token block's
-BlockSpec indexes its own model's gathered count rows and topic totals —
-self-exclusion and w_bits fixed-point rescaling are the same tile body, so
-the fused batch launch is exactly M independent single-model sweeps.
+BlockSpec (model axis squeezed) indexes its own model's gathered count
+rows and topic totals — self-exclusion and w_bits fixed-point rescaling
+are the same tile body, so the fused batch launch is exactly M independent
+single-model sweeps.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core import quant
+from repro.kernels import tiling
+from repro.kernels.tiling import pad_tokens
 
 
 def _resample_tile(
@@ -53,8 +66,9 @@ def _resample_tile(
 ):
     """The shared (TB, K) score+Gumbel-max tile body.
 
-    Both the single-model and the model-grid batched kernels call this, so
-    a batched launch is bit-for-bit M independent single-model tiles.
+    `tot` is (1, K); `z` and `w` are (TB,). Both the single-model and the
+    model-grid batched kernels call this, so a batched launch is
+    bit-for-bit M independent single-model tiles.
     """
     if w_bits is not None:
         scale = 2.0 ** -(w_bits + 1)
@@ -72,7 +86,7 @@ def _resample_tile(
 
     rd = jnp.maximum(rows_d - own, 0.0)
     rw = jnp.maximum(rows_w - own, 0.0)
-    tt = jnp.maximum(tot[None, :] - own, 1e-9)
+    tt = jnp.maximum(tot - own, 1e-9)
     logits = jnp.log(rd + alpha) + jnp.log(rw + beta) - jnp.log(tt + beta_bar)
     z_new = jnp.argmax(logits + g, axis=-1).astype(z.dtype)
     return jnp.where(w > 0.0, z_new, z)
@@ -92,12 +106,14 @@ def _gibbs_kernel(
     beta_bar: float,
     w_bits: int | None,
 ):
-    z_out_ref[...] = _resample_tile(
+    # The batched kernel's squeezed model axis makes its refs look exactly
+    # like these, so one body serves both grids.
+    z_out_ref[0] = _resample_tile(
         rows_d_ref[...],
         rows_w_ref[...],
         tot_ref[...],
-        z_ref[...],
-        w_ref[...],
+        z_ref[0],
+        w_ref[0],
         g_ref[...],
         alpha=alpha,
         beta=beta,
@@ -106,34 +122,23 @@ def _gibbs_kernel(
     )
 
 
-def _gibbs_kernel_batched(
-    rows_d_ref,
-    rows_w_ref,
-    tot_ref,
-    z_ref,
-    w_ref,
-    g_ref,
-    z_out_ref,
-    *,
-    alpha: float,
-    beta: float,
-    beta_bar: float,
-    w_bits: int | None,
-):
-    # Block shapes carry a leading model dim of 1: this grid step's token
-    # block indexes *its own model's* gathered count rows and totals.
-    z_out_ref[0] = _resample_tile(
-        rows_d_ref[0],
-        rows_w_ref[0],
-        tot_ref[0],
-        z_ref[0],
-        w_ref[0],
-        g_ref[0],
-        alpha=alpha,
-        beta=beta,
-        beta_bar=beta_bar,
-        w_bits=w_bits,
-    )
+def _dequant_codes(codes, bits: int):
+    """uint8 code tile -> f32 rows. The v5e compiler has no uint8 -> f32
+    cast, so codes widen through int32. Nibble-packed tiles hold topic t
+    in the low nibble of byte t and topic t + K/2 in the high nibble
+    (`pack_halves`), so unpacking is two masks and one 128-aligned lane
+    concatenation."""
+    x = codes.astype(jnp.int32)
+    if bits == 4:
+        x = jnp.concatenate([x & 0x0F, (x >> 4) & 0x0F], axis=-1)
+    return x.astype(jnp.float32)
+
+
+def pack_halves(codes: jax.Array) -> jax.Array:
+    """(N, K) uint8 4-bit codes -> (N, K/2) bytes in the kernel's nibble
+    order (low = topic t, high = topic t + K/2; K a multiple of 256)."""
+    half = codes.shape[-1] // 2
+    return (codes[..., :half] | (codes[..., half:] << 4)).astype(jnp.uint8)
 
 
 def _gibbs_kernel_quant(
@@ -150,28 +155,23 @@ def _gibbs_kernel_quant(
     beta: float,
     beta_bar: float,
     bits: int,
-    k: int,
 ):
     """Tile body for *packed* word-topic rows (QuantSpec int8/int4_packed).
 
     The gathered `n_wt` rows arrive as uint8 codes — nibble-packed for
     bits=4 — plus one float32 scale per token row, and are dequantized
     *inside* the tile: the VMEM (and HBM→VMEM) footprint of the dominant
-    input drops 4x/8x vs f32 rows, which is what lets the packed path run
-    larger token blocks. Doc-topic rows and topic totals stay exact f32
-    (they are small, and exact self-exclusion on `n_dt` is what keeps the
-    sampler's per-document bookkeeping honest).
+    input drops 4x/8x vs f32 rows. Doc-topic rows and topic totals stay
+    exact f32 (they are small, and exact self-exclusion on `n_dt` is what
+    keeps the sampler's per-document bookkeeping honest).
     """
-    codes = codes_w_ref[...]
-    if bits == 4:
-        codes = quant.unpack_nibbles_jnp(codes, k)
-    rows_w = codes.astype(jnp.float32) * scales_w_ref[...][:, None]
-    z_out_ref[...] = _resample_tile(
+    rows_w = _dequant_codes(codes_w_ref[...], bits) * scales_w_ref[0][:, None]
+    z_out_ref[0] = _resample_tile(
         rows_d_ref[...],
         rows_w,
         tot_ref[...],
-        z_ref[...],
-        w_ref[...],
+        z_ref[0],
+        w_ref[0],
         g_ref[...],
         alpha=alpha,
         beta=beta,
@@ -193,41 +193,50 @@ def gibbs_resample_blocked_quant(
     beta: float,
     beta_bar: float,
     bits: int,
-    token_block: int = 256,
-    interpret: bool = True,
+    interpret: bool,
+    token_block: Optional[int] = None,
 ) -> jax.Array:
     """Packed-row variant of `gibbs_resample_blocked`: same grid and
     sampling semantics, but the word-topic input is quantized codes that
     the tile body dequantizes in VMEM. For bits=4 the caller packs two
-    codes per byte (pad K so K//2 stays lane-aligned)."""
+    codes per byte with `pack_halves` (pad K to a multiple of 256)."""
     n, k = rows_d.shape
-    assert n % token_block == 0, (n, token_block)
     assert k % 128 == 0, k
     kc = codes_w.shape[-1]
     assert kc == (k // 2 if bits == 4 else k), (kc, k, bits)
-    grid = (n // token_block,)
+    tb = tiling.resolve(token_block, k, 8 * k + kc)
+    npad = -(-n // tb) * tb
 
     kern = functools.partial(
         _gibbs_kernel_quant,
-        alpha=alpha, beta=beta, beta_bar=beta_bar, bits=bits, k=k,
+        alpha=alpha, beta=beta, beta_bar=beta_bar, bits=bits,
     )
-    return pl.pallas_call(
+    row = pl.BlockSpec((tb, k), lambda i: (i, 0))
+    tok = pl.BlockSpec((1, tb), lambda i: (0, i))
+    out = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(npad // tb,),
         in_specs=[
-            pl.BlockSpec((token_block, kc), lambda i: (i, 0)),
-            pl.BlockSpec((token_block,), lambda i: (i,)),
-            pl.BlockSpec((token_block, k), lambda i: (i, 0)),
-            pl.BlockSpec((k,), lambda _i: (0,)),
-            pl.BlockSpec((token_block,), lambda i: (i,)),
-            pl.BlockSpec((token_block,), lambda i: (i,)),
-            pl.BlockSpec((token_block, k), lambda i: (i, 0)),
+            pl.BlockSpec((tb, kc), lambda i: (i, 0)),
+            tok,
+            row,
+            pl.BlockSpec((1, k), lambda _i: (0, 0)),
+            tok,
+            tok,
+            row,
         ],
-        out_specs=pl.BlockSpec((token_block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), z.dtype),
+        out_specs=tok,
+        out_shape=jax.ShapeDtypeStruct((1, npad), z.dtype),
         interpret=interpret,
         name="lda_gibbs_resample_quant",
-    )(codes_w, scales_w, rows_d, tot, z, weights, gumbel)
+    )(pad_tokens(codes_w, npad, 0),
+      pad_tokens(scales_w, npad, 0)[None],
+      pad_tokens(rows_d, npad, 0),
+      tot[None],
+      pad_tokens(z, npad, 0)[None],
+      pad_tokens(weights, npad, 0, 0.0)[None],
+      pad_tokens(gumbel, npad, 0, 0.0))
+    return out[0, :n]
 
 
 def gibbs_resample_blocked(
@@ -241,36 +250,38 @@ def gibbs_resample_blocked(
     alpha: float,
     beta: float,
     beta_bar: float,
+    interpret: bool,
     w_bits: int | None = None,
-    token_block: int = 256,
-    interpret: bool = True,
+    token_block: Optional[int] = None,
 ) -> jax.Array:
-    """Tiled pallas_call over token blocks. N must be a multiple of
-    token_block and K a multiple of 128 (caller pads)."""
+    """Tiled pallas_call over token blocks. K must be a multiple of 128
+    (caller pads); N is padded here to a multiple of the token tile."""
     n, k = rows_d.shape
-    assert n % token_block == 0, (n, token_block)
     assert k % 128 == 0, k
-    grid = (n // token_block,)
+    tb = tiling.resolve(token_block, k, 12 * k)
+    npad = -(-n // tb) * tb
 
     kern = functools.partial(
         _gibbs_kernel, alpha=alpha, beta=beta, beta_bar=beta_bar, w_bits=w_bits
     )
-    return pl.pallas_call(
+    row = pl.BlockSpec((tb, k), lambda i: (i, 0))
+    tok = pl.BlockSpec((1, tb), lambda i: (0, i))
+    out = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((token_block, k), lambda i: (i, 0)),
-            pl.BlockSpec((token_block, k), lambda i: (i, 0)),
-            pl.BlockSpec((k,), lambda _i: (0,)),
-            pl.BlockSpec((token_block,), lambda i: (i,)),
-            pl.BlockSpec((token_block,), lambda i: (i,)),
-            pl.BlockSpec((token_block, k), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((token_block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), z.dtype),
+        grid=(npad // tb,),
+        in_specs=[row, row, pl.BlockSpec((1, k), lambda _i: (0, 0)),
+                  tok, tok, row],
+        out_specs=tok,
+        out_shape=jax.ShapeDtypeStruct((1, npad), z.dtype),
         interpret=interpret,
         name="lda_gibbs_resample",
-    )(rows_d, rows_w, tot, z, weights, gumbel)
+    )(pad_tokens(rows_d, npad, 0),
+      pad_tokens(rows_w, npad, 0),
+      tot[None],
+      pad_tokens(z, npad, 0)[None],
+      pad_tokens(weights, npad, 0, 0.0)[None],
+      pad_tokens(gumbel, npad, 0, 0.0))
+    return out[0, :n]
 
 
 def gibbs_resample_blocked_batched(
@@ -284,9 +295,9 @@ def gibbs_resample_blocked_batched(
     alpha: float,
     beta: float,
     beta_bar: float,
+    interpret: bool,
     w_bits: int | None = None,
-    token_block: int = 256,
-    interpret: bool = True,
+    token_block: Optional[int] = None,
 ) -> jax.Array:
     """One kernel launch over M stacked models: grid (M, N // token_block).
 
@@ -297,27 +308,29 @@ def gibbs_resample_blocked_batched(
     and w_bits fixed-point weighting.
     """
     m, n, k = rows_d.shape
-    assert n % token_block == 0, (n, token_block)
     assert k % 128 == 0, k
-    grid = (m, n // token_block)
+    tb = tiling.resolve(token_block, k, 12 * k)
+    npad = -(-n // tb) * tb
 
     kern = functools.partial(
-        _gibbs_kernel_batched,
+        _gibbs_kernel,
         alpha=alpha, beta=beta, beta_bar=beta_bar, w_bits=w_bits,
     )
-    return pl.pallas_call(
+    row = pl.BlockSpec((None, tb, k), lambda j, i: (j, i, 0))
+    tok = pl.BlockSpec((None, 1, tb), lambda j, i: (j, 0, i))
+    out = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, token_block, k), lambda j, i: (j, i, 0)),
-            pl.BlockSpec((1, token_block, k), lambda j, i: (j, i, 0)),
-            pl.BlockSpec((1, k), lambda j, _i: (j, 0)),
-            pl.BlockSpec((1, token_block), lambda j, i: (j, i)),
-            pl.BlockSpec((1, token_block), lambda j, i: (j, i)),
-            pl.BlockSpec((1, token_block, k), lambda j, i: (j, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, token_block), lambda j, i: (j, i)),
-        out_shape=jax.ShapeDtypeStruct((m, n), z.dtype),
+        grid=(m, npad // tb),
+        in_specs=[row, row, pl.BlockSpec((None, 1, k), lambda j, _i: (j, 0, 0)),
+                  tok, tok, row],
+        out_specs=tok,
+        out_shape=jax.ShapeDtypeStruct((m, 1, npad), z.dtype),
         interpret=interpret,
         name="lda_gibbs_resample_batched",
-    )(rows_d, rows_w, tot, z, weights, gumbel)
+    )(pad_tokens(rows_d, npad, 1),
+      pad_tokens(rows_w, npad, 1),
+      tot[:, None],
+      pad_tokens(z, npad, 1)[:, None],
+      pad_tokens(weights, npad, 1, 0.0)[:, None],
+      pad_tokens(gumbel, npad, 1, 0.0))
+    return out[:, 0, :n]

@@ -3,8 +3,9 @@
 `sweep_resample(cfg, state, corpus, key)` is a drop-in replacement for the
 score+sample inner stage of `repro.core.gibbs.sweep`: counts are gathered
 (XLA gather — efficient on TPU), the kernel fuses scoring and Gumbel-max
-sampling per VMEM tile, and counts are rebuilt outside. On CPU the kernel
-body runs in interpret mode.
+sampling per VMEM tile, and counts are rebuilt outside. The kernel is
+compiled by Mosaic on a TPU; on the CPU backend its body runs in
+interpret mode.
 """
 
 from __future__ import annotations
@@ -20,6 +21,7 @@ from repro.kernels.lda_gibbs.kernel import (
     gibbs_resample_blocked,
     gibbs_resample_blocked_batched,
     gibbs_resample_blocked_quant,
+    pack_halves,
 )
 
 
@@ -27,44 +29,39 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-@partial(jax.jit, static_argnums=(0, 4))
+@partial(jax.jit, static_argnums=(0,))
 def sweep_resample(
     cfg: LDAConfig,
     state: LDAState,
     corpus: Corpus,
     key: jax.Array,
-    token_block: int = 256,
 ) -> jax.Array:
     """One full resampling pass; returns new z (counts rebuilt by caller).
 
-    With a packed `cfg.quant` spec (int8/int4_packed) the word-topic rows
-    take the quantized kernel: the (V, K) table is row-quantized once per
-    sweep (counts are sweep-stale by design, so one lossy snapshot per
-    sweep is the §4.3 story at table granularity), the uint8 code rows are
-    gathered instead of f32/int32 rows, and the tile body dequantizes in
-    VMEM.
+    The kernel sizes its token tile by K (`kernels.tiling`). With a
+    packed `cfg.quant` spec (int8/int4_packed) the word-topic rows take
+    the quantized kernel: the (V, K) table is
+    row-quantized once per sweep (counts are sweep-stale by design, so one
+    lossy snapshot per sweep is the §4.3 story at table granularity), the
+    uint8 code rows are gathered instead of f32/int32 rows, and the tile
+    body dequantizes in VMEM.
     """
     spec = cfg.quant_spec
-    n = corpus.num_tokens
     k = cfg.num_topics
     kp_base = -(-k // 128) * 128  # lane-pad K to 128
     kp = kp_base
     if spec.packed and spec.bits == 4:
         kp = -(-k // 256) * 256  # keep the nibble-packed lane dim at 128
-    npad = -(-n // token_block) * token_block
 
-    def pad2(x, fill=0):
-        return jnp.pad(
-            x, ((0, npad - n), (0, kp - k)), constant_values=fill
-        )
+    def padk(x, fill=0):
+        return jnp.pad(x, ((0, 0), (0, kp - k)), constant_values=fill)
 
-    def pad1(x, fill=0):
-        return jnp.pad(x, (0, npad - n), constant_values=fill)
-
-    # Noise is drawn at the mode-independent base width so a packed sweep
-    # consumes the *same* per-topic gumbel columns as the exact sweep from
-    # the same key (the int4 lane over-padding only adds -inf columns).
-    gumbel = jax.random.gumbel(key, (npad, kp_base), jnp.float32)
+    # Noise is drawn at the true token count and the mode-independent base
+    # width, so a sweep's draws do not depend on the kernel's token tile
+    # and a packed sweep consumes the *same* per-topic gumbel columns as
+    # the exact sweep from the same key (the int4 lane over-padding only
+    # adds -inf columns).
+    gumbel = jax.random.gumbel(key, (corpus.num_tokens, kp_base), jnp.float32)
     # Padded topics get -inf scores via zero counts + -inf gumbel.
     gumbel = jnp.where(jnp.arange(kp_base)[None, :] < k, gumbel, -jnp.inf)
     if kp != kp_base:
@@ -75,71 +72,59 @@ def sweep_resample(
         # Quantize the stale table once, gather packed rows per token.
         n_wt_real = codec.decode_array(cfg, state.n_wt)
         codes, scales = quant.quantize_rows_jnp(n_wt_real, spec.bits)
-        codes_rows = pad2(codes[corpus.words])
+        codes_rows = padk(codes[corpus.words])
         if spec.bits == 4:
-            codes_rows = quant.pack_nibbles_jnp(codes_rows)
-        rows_d = pad2(codec.decode_array(cfg, state.n_dt[corpus.docs]))
-        tot = jnp.pad(codec.decode_array(cfg, state.n_t), (0, kp - k))
-        z_new = gibbs_resample_blocked_quant(
+            codes_rows = pack_halves(codes_rows)
+        return gibbs_resample_blocked_quant(
             codes_rows,
-            pad1(scales[corpus.words], 0.0),
-            rows_d,
-            tot,
-            pad1(state.z),
-            pad1(corpus.weights, 0.0),
+            scales[corpus.words],
+            padk(codec.decode_array(cfg, state.n_dt[corpus.docs])),
+            jnp.pad(codec.decode_array(cfg, state.n_t), (0, kp - k)),
+            state.z,
+            corpus.weights,
             gumbel,
             alpha=cfg.alpha,
             beta=cfg.beta,
             beta_bar=cfg.beta_bar,
             bits=spec.bits,
-            token_block=token_block,
             interpret=_interpret(),
         )
-        return z_new[:n]
 
     # Fixed-point counts are gathered *as int32* and rescaled inside the
     # kernel (saves the full (D,K)/(V,K) float materialization of from_fixed).
-    rows_d = state.n_dt[corpus.docs]  # (N, K) gather outside the kernel
-    rows_w = state.n_wt[corpus.words]
-    n_t = state.n_t
-
-    z_new = gibbs_resample_blocked(
-        pad2(rows_d),
-        pad2(rows_w),
-        jnp.pad(n_t, (0, kp - k)),
-        pad1(state.z),
-        pad1(corpus.weights, 0.0),
+    return gibbs_resample_blocked(
+        padk(state.n_dt[corpus.docs]),  # (N, K) gathers outside the kernel
+        padk(state.n_wt[corpus.words]),
+        jnp.pad(state.n_t, (0, kp - k)),
+        state.z,
+        corpus.weights,
         gumbel,
         alpha=cfg.alpha,
         beta=cfg.beta,
         beta_bar=cfg.beta_bar,
         w_bits=cfg.w_bits,
-        token_block=token_block,
         interpret=_interpret(),
     )
-    return z_new[:n]
 
 
-@partial(jax.jit, static_argnums=(0, 4))
+@partial(jax.jit, static_argnums=(0,))
 def sweep(
     cfg: LDAConfig,
     state: LDAState,
     corpus: Corpus,
     key: jax.Array,
-    token_block: int = 256,
 ) -> LDAState:
     """Full kernel-path Gibbs sweep (resample + count rebuild)."""
-    z_new = sweep_resample(cfg, state, corpus, key, token_block)
+    z_new = sweep_resample(cfg, state, corpus, key)
     return codec.rebuild_state(cfg, corpus, z_new)
 
 
-@partial(jax.jit, static_argnums=(0, 4))
+@partial(jax.jit, static_argnums=(0,))
 def sweep_many(
     cfg: LDAConfig,
     states: LDAState,  # stacked: z (M, N), n_dt (M, D, K), n_wt (M, V, K)
     corpora: Corpus,  # stacked: docs/words/weights (M, N)
     keys: jax.Array,  # (M, 2) one PRNG key per model
-    token_block: int = 256,
 ) -> LDAState:
     """One fused Gibbs sweep over M stacked models (single kernel launch).
 
@@ -148,43 +133,39 @@ def sweep_many(
     per-model document capacity (`serving.batch_engine` buckets and pads).
     Gathers run per model (an (M, N) batched XLA gather), the model-grid
     kernel fuses score+sample for all M models, and counts are rebuilt
-    per model by a vmapped scatter-add.
+    per model by a vmapped scatter-add. Model i draws exactly the noise
+    the single-model `sweep` draws from keys[i].
     """
-    m, n = corpora.docs.shape
+    n = corpora.docs.shape[1]
     k = cfg.num_topics
     kp = -(-k // 128) * 128
-    npad = -(-n // token_block) * token_block
 
     rows_d = jax.vmap(lambda n_dt, d: n_dt[d])(states.n_dt, corpora.docs)
     rows_w = jax.vmap(lambda n_wt, w: n_wt[w])(states.n_wt, corpora.words)
 
-    def pad3(x, fill=0):
+    def padk(x, fill=0):
         return jnp.pad(
-            x, ((0, 0), (0, npad - n), (0, kp - k)), constant_values=fill
+            x, ((0, 0), (0, 0), (0, kp - k)), constant_values=fill
         )
 
-    def pad2(x, fill=0):
-        return jnp.pad(x, ((0, 0), (0, npad - n)), constant_values=fill)
-
     gumbel = jax.vmap(
-        lambda kk: jax.random.gumbel(kk, (npad, kp), jnp.float32)
+        lambda kk: jax.random.gumbel(kk, (n, kp), jnp.float32)
     )(keys)
     # Padded topics get -inf scores via zero counts + -inf gumbel.
     gumbel = jnp.where(jnp.arange(kp)[None, None, :] < k, gumbel, -jnp.inf)
 
     z_new = gibbs_resample_blocked_batched(
-        pad3(rows_d),
-        pad3(rows_w),
+        padk(rows_d),
+        padk(rows_w),
         jnp.pad(states.n_t, ((0, 0), (0, kp - k))),
-        pad2(states.z),
-        pad2(corpora.weights, 0.0),
+        states.z,
+        corpora.weights,
         gumbel,
         alpha=cfg.alpha,
         beta=cfg.beta,
         beta_bar=cfg.beta_bar,
         w_bits=cfg.w_bits,
-        token_block=token_block,
         interpret=_interpret(),
-    )[:, :n]
+    )
     return jax.vmap(lambda co, z: codec.rebuild_state(cfg, co, z))(
         corpora, z_new)

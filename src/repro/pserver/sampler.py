@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.core import codec
 from repro.core.types import Corpus, LDAConfig, LDAState, init_state
+from repro.launch.mesh import make_data_mesh
 from repro.obs import metrics, timers
 from repro.pserver import sync as sync_lib
 from repro.pserver import topology
@@ -67,7 +68,7 @@ class PServerFit:
 
     def __init__(self, mesh=None, block: int = 4096, staleness: int = 1,
                  local: str = "auto", cap: Optional[int] = None,
-                 mh_steps: int = 4, token_block: int = 256):
+                 mh_steps: int = 4):
         if local not in ("auto", "gibbs", "pallas", "mh"):
             raise ValueError(f"unknown pserver local engine {local!r}")
         if staleness < 1:
@@ -78,7 +79,6 @@ class PServerFit:
         self.local = local
         self.cap = cap
         self.mh_steps = mh_steps
-        self.token_block = token_block
         self._plans: dict[tuple, topology.PServerPlan] = {}
         self._programs: dict[tuple, object] = {}
 
@@ -86,8 +86,7 @@ class PServerFit:
 
     def _mesh(self):
         if self.mesh is None:
-            self.mesh = jax.make_mesh(
-                (jax.device_count(), 1), ("data", "model"))
+            self.mesh = make_data_mesh()
         return self.mesh
 
     def _local(self) -> str:
@@ -128,13 +127,13 @@ class PServerFit:
         mesh = self._mesh()
         key = (cfg, id(mesh), plan.d_local, plan.t_local, plan.cap,
                plan.v_pad, num_sweeps, staleness, self.block, self._local(),
-               self.mh_steps, self.token_block)
+               self.mh_steps)
         return self._lru_get(
             self._programs, key,
             lambda: make_pserver_program(
                 cfg, mesh, plan, num_sweeps=num_sweeps, staleness=staleness,
                 block=self.block, local=self._local(),
-                mh_steps=self.mh_steps, token_block=self.token_block))
+                mh_steps=self.mh_steps))
 
     # -- boundary -----------------------------------------------------------
 
@@ -159,10 +158,9 @@ class PServerFit:
 
         timer = timers.DeviceTimer(
             _FIT_SECONDS, local=self._local()).start()
-        with mesh:
-            z_p, n_dt_p, n_wt, n_t = prog(
-                jnp.asarray(plan.docs_l), jnp.asarray(plan.words_l),
-                z_p, wts_p, sup, n_dt_p, cache0, real.n_t, keys)
+        z_p, n_dt_p, n_wt, n_t = prog(
+            jnp.asarray(plan.docs_l), jnp.asarray(plan.words_l),
+            z_p, wts_p, sup, n_dt_p, cache0, real.n_t, keys)
         timer.sync(n_wt)
         # Sync accounting mirrors the program's schedule: one model sync
         # per *full* staleness window (`divmod` in sweep.py — tail sweeps

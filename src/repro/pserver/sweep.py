@@ -73,7 +73,6 @@ def make_pserver_program(
     block: int = 4096,
     local: str = "gibbs",
     mh_steps: int = 4,
-    token_block: int = 256,
 ):
     """Build the jit-able pserver program for one (mesh, plan) pair.
 
@@ -103,25 +102,18 @@ def make_pserver_program(
     def _local_pallas(z, docs, words, wts, n_dt, cache, n_t, kk):
         from repro.kernels.lda_gibbs.kernel import gibbs_resample_blocked
 
-        n = docs.shape[0]
         kp = -(-k // 128) * 128
-        npad = -(-n // token_block) * token_block
 
-        def pad2(x):
-            return jnp.pad(x, ((0, npad - n), (0, kp - k)))
+        def padk(x):
+            return jnp.pad(x, ((0, 0), (0, kp - k)))
 
-        def pad1(x, fill=0):
-            return jnp.pad(x, (0, npad - n), constant_values=fill)
-
-        gumbel = jax.random.gumbel(kk, (npad, kp), jnp.float32)
+        gumbel = jax.random.gumbel(kk, (docs.shape[0], kp), jnp.float32)
         gumbel = jnp.where(jnp.arange(kp)[None, :] < k, gumbel, -jnp.inf)
-        z_new = gibbs_resample_blocked(
-            pad2(n_dt[docs]), pad2(cache[words]), jnp.pad(n_t, (0, kp - k)),
-            pad1(z), pad1(wts, 0.0), gumbel,
+        return gibbs_resample_blocked(
+            padk(n_dt[docs]), padk(cache[words]), jnp.pad(n_t, (0, kp - k)),
+            z, wts, gumbel,
             alpha=cfg.alpha, beta=cfg.beta, beta_bar=cfg.beta_bar,
-            w_bits=None, token_block=token_block,
-            interpret=jax.default_backend() == "cpu")
-        return z_new[:n]
+            w_bits=None, interpret=jax.default_backend() == "cpu")
 
     def _local_mh(z, docs, words, wts, n_dt, cache, n_t, kk):
         # AliasLDA word/doc cycle proposals from the *window-stale* support

@@ -24,6 +24,9 @@ def run_with_devices(code: str, n_devices: int = 4,
     """Execute `code` under `n_devices` simulated host devices; the code
     must print a JSON object as its last stdout line."""
     env = dict(os.environ)
+    # A CPU virtual-device simulation; the chip's four-device path is
+    # `chip_smoke.py --chips 4`.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={n_devices}")
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")

@@ -11,6 +11,8 @@ from repro.kernels.lda_gibbs import ops as kops
 from repro.kernels.lda_gibbs.kernel import (
     gibbs_resample_blocked,
     gibbs_resample_blocked_batched,
+    gibbs_resample_blocked_quant,
+    pack_halves,
 )
 from repro.kernels.lda_gibbs.ref import resample_tile
 
@@ -169,3 +171,30 @@ def test_kernel_keeps_padding_assignments():
         alpha=0.1, beta=0.01, beta_bar=1.28, interpret=True,
     )
     np.testing.assert_array_equal(np.asarray(out), np.asarray(z))
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_quant_kernel_matches_ref_on_dequantized_rows(bits):
+    """Packed word-topic rows (uint8 codes, split-halves nibbles for
+    bits=4) dequantized in the tile == the oracle fed the dequantized
+    rows, bit for bit; N is deliberately not a multiple of the tile."""
+    rng = np.random.default_rng(5 + bits)
+    n, k = 700, 256
+    levels = 255 if bits == 8 else 15
+    codes = jnp.asarray(rng.integers(0, levels + 1, (n, k)).astype(np.uint8))
+    scales = jnp.asarray(rng.random(n).astype(np.float32) * 3.0)
+    rows_d = _random_counts(rng, n, k, np.float32)
+    tot = jnp.asarray(rng.integers(1, 500, k).astype(np.float32))
+    z = jnp.asarray(rng.integers(0, k, n).astype(np.int32))
+    wts = jnp.asarray(
+        (rng.random(n) * (rng.random(n) > 0.1)).astype(np.float32))
+    g = jax.random.gumbel(jax.random.PRNGKey(4), (n, k), jnp.float32)
+
+    out = gibbs_resample_blocked_quant(
+        pack_halves(codes) if bits == 4 else codes, scales, rows_d, tot,
+        z, wts, g, alpha=0.1, beta=0.01, beta_bar=0.01 * k, bits=bits,
+        token_block=256, interpret=True,
+    )
+    rows_w = codes.astype(jnp.float32) * scales[:, None]
+    ref = resample_tile(rows_d, rows_w, tot, z, wts, g, 0.1, 0.01, 0.01 * k)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))

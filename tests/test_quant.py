@@ -158,13 +158,10 @@ def test_jnp_twins_match_numpy():
         codes_j, scales_j = quant.quantize_rows_jnp(jnp.asarray(x), bits)
         np.testing.assert_allclose(np.asarray(scales_j), scales_np,
                                    rtol=1e-6)
-        packed_j = (quant.pack_nibbles_jnp(codes_j) if bits == 4
-                    else codes_j)
-        assert np.array_equal(np.asarray(packed_j), codes_np)
-        unpacked = quant.unpack_nibbles_jnp(jnp.asarray(codes_np), 11)
+        packed_j = np.asarray(codes_j)
         if bits == 4:
-            assert np.array_equal(np.asarray(unpacked),
-                                  quant.unpack_nibbles(codes_np, 11))
+            packed_j = quant.pack_nibbles(packed_j)
+        assert np.array_equal(packed_j, codes_np)
     fq = quant.fake_quantize_rows(x, 8)
     fq_j = np.asarray(quant.fake_quantize_rows(jnp.asarray(x), 8))
     np.testing.assert_allclose(fq_j, fq, rtol=1e-5, atol=1e-5)

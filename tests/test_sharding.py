@@ -8,6 +8,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import configs
+from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.models.params import PDef, partition_specs
 from repro.sharding import specs as S
@@ -75,9 +76,10 @@ def test_constrain_noop_outside_context():
 
 def test_jit_train_step_on_1x1_mesh():
     """Full sharded-jit path (in_shardings from the same code the dry-run
-    uses) on a 1x1 host mesh — numerics must match the unsharded step."""
+    uses) on the dry-run's 1x1 host mesh — numerics must match the
+    unsharded step."""
     cfg = configs.get("gemma2-9b").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     pspecs = M.model_pspecs(cfg, mesh)
     named = lambda t: jax.tree.map(
         lambda s: NamedSharding(mesh, s), t,
