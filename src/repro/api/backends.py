@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.core.codec import decode_state, encode_state
 from repro.core.types import Corpus, LDAConfig, LDAState, init_state
+from repro.obs import trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,6 +226,7 @@ class _BaseSampler:
     `gibbs.run` (split for init, then one subkey per sweep) so backends
     are drop-in comparable from identical seeds."""
 
+    @trace.span("sampler.run")
     def run(self, cfg, corpus, key, num_sweeps, state=None):
         if state is None:
             key, sub = jax.random.split(key)
@@ -249,6 +251,7 @@ class JnpSampler(_BaseSampler):
 
         return gibbs.sweep(cfg, state, corpus, key, self.block)
 
+    @trace.span("sampler.run")
     def run(self, cfg, corpus, key, num_sweeps, state=None):
         # gibbs.run scans the sweeps under one jit — keep that fast path.
         from repro.core import gibbs
@@ -369,6 +372,7 @@ class PServerSampler(_BaseSampler):
     def sweep(self, cfg, state, corpus, key):
         return self._fit.sweep(cfg, state, corpus, key)
 
+    @trace.span("sampler.run")
     def run(self, cfg, corpus, key, num_sweeps, state=None):
         return self._fit.run(cfg, corpus, key, num_sweeps, state=state)
 
@@ -422,6 +426,7 @@ class AliasSampler(_BaseSampler):
         return encode_state(
             cfg, alias.mh_sweep(cfg, real, corpus, key, self.mh_steps))
 
+    @trace.span("sampler.run_many")
     def run_many(self, cfg, corpora, keys, num_sweeps, states=None):
         """Batched multi-sweep alias fit/refit (cold when `states` is
         None): all sweeps of all M models scanned under one jit
@@ -482,6 +487,7 @@ class SparseSampler(_BaseSampler):
     def sweep(self, cfg, state, corpus, key):
         return self._sequential(cfg, state, corpus, key, 1)
 
+    @trace.span("sampler.run")
     def run(self, cfg, corpus, key, num_sweeps, state=None):
         if state is None:
             key, sub = jax.random.split(key)
@@ -525,6 +531,7 @@ class BatchedSampler(_BaseSampler):
         return batch.sweep_batch(
             cfg, states, corpora, keys, self.block, self._path())
 
+    @trace.span("sampler.run_many")
     def run_many(self, cfg, corpora, keys, num_sweeps, states=None):
         """Batched multi-sweep fit/refit: cold when `states` is None."""
         from repro.core import batch

@@ -35,7 +35,7 @@ from repro.core import views as views_lib
 from repro.core.rlda import Review, RLDACorpus
 from repro.core.types import LDAState
 from repro.core.views import ModelView
-from repro.obs import metrics, timers
+from repro.obs import metrics, timers, trace
 
 #: Backend-labelled service-op latency — the tier-attribution histogram
 #: ("where do the milliseconds go") the ISSUE's motivation asks for. Device
@@ -251,6 +251,7 @@ class VedaliaService:
             prep, backend=backend, num_sweeps=num_sweeps, seed=seed,
             device_kind=device_kind)
 
+    @trace.span("service.fit_prepared")
     def fit_prepared(
         self,
         prep: RLDACorpus,
@@ -276,6 +277,7 @@ class VedaliaService:
             handle_id=self._new_id(), prep=prep, model=model,
             backend=backend, sweeps_run=sweeps))
 
+    @trace.span("service.fit_batch")
     def fit_batch(
         self,
         review_sets: Sequence[Sequence[Review]],
@@ -388,6 +390,7 @@ class VedaliaService:
                 backend, num_tokens=prep.corpus.num_tokens, task="update"),
             sweeps_run=sweeps_run))
 
+    @trace.span("service.refine")
     def refine(
         self,
         handle: ModelHandle,
@@ -410,6 +413,7 @@ class VedaliaService:
         handle.backend = backend
         return handle
 
+    @trace.span("service.refine_many")
     def refine_many(
         self,
         handles: Sequence[ModelHandle],
@@ -577,9 +581,14 @@ class VedaliaService:
             handle_id=handle.handle_id, topic_id=int(topic_id),
             review_ids=ids)
 
+    @trace.span("service.perplexity")
     def perplexity(self, handle: ModelHandle) -> float:
-        return float(perplexity_lib.perplexity(
-            handle.cfg, handle.state, handle.model.corpus))
+        ppx = perplexity_lib.perplexity(
+            handle.cfg, handle.state, handle.model.corpus)
+        # The read waits for every program queued before it (a served
+        # refit's sweeps and count rebuilds): device time, not service work.
+        with trace.span("device.wait"):
+            return float(ppx)
 
     def heldout_perplexity(
         self, handle: ModelHandle, reviews: Sequence[Review]

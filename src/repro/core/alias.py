@@ -105,15 +105,16 @@ def build_alias_tables(probs: jax.Array) -> tuple[jax.Array, jax.Array]:
     flushed) fall back to an explicit uniform distribution rather than
     normalizing against an epsilon floor.
     """
-    probs = jnp.asarray(probs, jnp.float32)
-    k = probs.shape[-1]
-    lead = probs.shape[:-1]
-    row_sum = probs.sum(-1, keepdims=True)
-    ok = row_sum > 0.0
-    mass = jnp.where(ok, probs * (k / jnp.where(ok, row_sum, 1.0)), 1.0)
-    flat = mass.reshape((-1, k))
-    thresh, alias = jax.vmap(_build_row)(flat)
-    return thresh.reshape(lead + (k,)), alias.reshape(lead + (k,))
+    with jax.named_scope("alias_tables"):
+        probs = jnp.asarray(probs, jnp.float32)
+        k = probs.shape[-1]
+        lead = probs.shape[:-1]
+        row_sum = probs.sum(-1, keepdims=True)
+        ok = row_sum > 0.0
+        mass = jnp.where(ok, probs * (k / jnp.where(ok, row_sum, 1.0)), 1.0)
+        flat = mass.reshape((-1, k))
+        thresh, alias = jax.vmap(_build_row)(flat)
+        return thresh.reshape(lead + (k,)), alias.reshape(lead + (k,))
 
 
 def build_alias_table(probs: jax.Array) -> tuple[jax.Array, jax.Array]:

@@ -32,6 +32,7 @@ state codec and the wire array codec of `repro.api.protocol`.
 
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from repro.core import fractional, quant
@@ -128,7 +129,8 @@ class StateCodec:
         """Scatter-rebuild counts from assignments and store (the
         post-sweep pattern shared by all backends: rebuild in real units,
         encode once)."""
-        return self.encode_state(build_counts(cfg, corpus, z))
+        with jax.named_scope("count_rebuild"):
+            return self.encode_state(build_counts(cfg, corpus, z))
 
     # -- read-only packed tables (int8 / int4_packed modes) -----------------
 

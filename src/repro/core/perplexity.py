@@ -30,6 +30,11 @@ def log_likelihood(
     cfg: LDAConfig, state: LDAState, corpus: Corpus, block: int = 8192
 ) -> jax.Array:
     """Total weighted token log-likelihood under point estimates."""
+    with jax.named_scope("perplexity"):
+        return _log_likelihood(cfg, state, corpus, block)
+
+
+def _log_likelihood(cfg, state, corpus, block):
     n_dt, n_wt, n_t = _real_counts(cfg, state)
     alpha_bar = cfg.alpha * cfg.num_topics
     theta = (n_dt + cfg.alpha) / (n_dt.sum(-1, keepdims=True) + alpha_bar)  # (D,K)

@@ -46,12 +46,13 @@ def _draws(key: jax.Array, n: int, k: int, mh_steps: int):
     one key per MH round, split 3-ways into bucket / alias / accept draws
     at the true token count (padding is appended afterwards)."""
     js, ups, uas = [], [], []
-    for k_step in jax.random.split(key, mh_steps):
-        kj, ku, ka = jax.random.split(k_step, 3)
-        js.append(jax.random.randint(kj, (n,), 0, k))
-        ups.append(jax.random.uniform(ku, (n,)))
-        uas.append(jax.random.uniform(ka, (n,)))
-    return jnp.stack(js), jnp.stack(ups), jnp.stack(uas)
+    with jax.named_scope("noise"):
+        for k_step in jax.random.split(key, mh_steps):
+            kj, ku, ka = jax.random.split(k_step, 3)
+            js.append(jax.random.randint(kj, (n,), 0, k))
+            ups.append(jax.random.uniform(ku, (n,)))
+            uas.append(jax.random.uniform(ka, (n,)))
+        return jnp.stack(js), jnp.stack(ups), jnp.stack(uas)
 
 
 @partial(jax.jit, static_argnums=(0, 4))
@@ -79,6 +80,9 @@ def mh_resample(
     k = cfg.num_topics
     kp = -(-k // 128) * 128  # lane-pad K to 128
 
+    def padk(x, fill=0):
+        return jnp.pad(x, ((0, 0), (0, kp - k)), constant_values=fill)
+
     # Stale proposal tables (word + doc cycles): built once per sweep from
     # the decoded counts by the parallel prefix-sum builder, then gathered
     # per token like the count rows. Fixed-point count rows are gathered
@@ -87,37 +91,35 @@ def mh_resample(
         n_wt_q = quant.fake_quantize_rows(
             codec.decode_array(cfg, state.n_wt), spec.bits)
         thresh_w, alias_w = alias_core.build_alias_tables(n_wt_q + cfg.beta)
-        rows_w = n_wt_q[corpus.words]
-        rows_d = codec.decode_array(cfg, state.n_dt[corpus.docs])
+        word_table = n_wt_q
         n_t = codec.decode_array(cfg, state.n_t)
         kernel_w_bits = None  # inputs already real-valued
     else:
         thresh_w, alias_w = alias_core.build_alias_tables(
             codec.decode_array(cfg, state.n_wt) + cfg.beta)
-        rows_w = state.n_wt[corpus.words]
-        rows_d = state.n_dt[corpus.docs]  # (N, K) gather outside the kernel
+        word_table = state.n_wt
         n_t = state.n_t
         kernel_w_bits = cfg.w_bits
     thresh_d, alias_d = alias_core.build_alias_tables(
         codec.decode_array(cfg, state.n_dt) + cfg.alpha)
-    thresh_w_rows = thresh_w[corpus.words]
-    alias_w_rows = alias_w[corpus.words]
-    thresh_d_rows = thresh_d[corpus.docs]
-    alias_d_rows = alias_d[corpus.docs]
+    with jax.named_scope("gather"):
+        rows_d = state.n_dt[corpus.docs]  # (N, K) gathers outside the kernel
+        if spec.packed:
+            rows_d = codec.decode_array(cfg, rows_d)
+        rows = (
+            padk(rows_d),
+            padk(word_table[corpus.words]),
+            jnp.pad(n_t, (0, kp - k)),
+            padk(thresh_w[corpus.words], 0.0),
+            padk(alias_w[corpus.words]),
+            padk(thresh_d[corpus.docs], 0.0),
+            padk(alias_d[corpus.docs]),
+        )
 
     j_prop, u_prop, u_acc = _draws(key, n, k, mh_steps)
 
-    def padk(x, fill=0):
-        return jnp.pad(x, ((0, 0), (0, kp - k)), constant_values=fill)
-
     return alias_mh_blocked(
-        padk(rows_d),
-        padk(rows_w),
-        jnp.pad(n_t, (0, kp - k)),
-        padk(thresh_w_rows, 0.0),
-        padk(alias_w_rows),
-        padk(thresh_d_rows, 0.0),
-        padk(alias_d_rows),
+        *rows,
         state.z,
         corpus.weights,
         j_prop,
@@ -169,28 +171,30 @@ def mh_sweep_many(
         codec.decode_array(cfg, states.n_wt) + cfg.beta)  # (M, V, K)
     thresh_d, alias_d = alias_core.build_alias_tables(
         codec.decode_array(cfg, states.n_dt) + cfg.alpha)  # (M, D, K)
-    rows_d = jax.vmap(lambda n_dt, d: n_dt[d])(states.n_dt, corpora.docs)
-    rows_w = jax.vmap(lambda n_wt, w: n_wt[w])(states.n_wt, corpora.words)
-    thresh_w_rows = jax.vmap(lambda t, w: t[w])(thresh_w, corpora.words)
-    alias_w_rows = jax.vmap(lambda a, w: a[w])(alias_w, corpora.words)
-    thresh_d_rows = jax.vmap(lambda t, d: t[d])(thresh_d, corpora.docs)
-    alias_d_rows = jax.vmap(lambda a, d: a[d])(alias_d, corpora.docs)
-
-    j_prop, u_prop, u_acc = jax.vmap(
-        lambda kk: _draws(kk, n, k, mh_steps))(keys)  # (M, S, N) each
 
     def padk(x, fill=0):
         return jnp.pad(
             x, ((0, 0), (0, 0), (0, kp - k)), constant_values=fill)
 
+    def rows_of(table, idx):
+        return jax.vmap(lambda t, i: t[i])(table, idx)
+
+    with jax.named_scope("gather"):
+        rows = (
+            padk(rows_of(states.n_dt, corpora.docs)),
+            padk(rows_of(states.n_wt, corpora.words)),
+            jnp.pad(states.n_t, ((0, 0), (0, kp - k))),
+            padk(rows_of(thresh_w, corpora.words), 0.0),
+            padk(rows_of(alias_w, corpora.words)),
+            padk(rows_of(thresh_d, corpora.docs), 0.0),
+            padk(rows_of(alias_d, corpora.docs)),
+        )
+
+    j_prop, u_prop, u_acc = jax.vmap(
+        lambda kk: _draws(kk, n, k, mh_steps))(keys)  # (M, S, N) each
+
     z_new = alias_mh_blocked_batched(
-        padk(rows_d),
-        padk(rows_w),
-        jnp.pad(states.n_t, ((0, 0), (0, kp - k))),
-        padk(thresh_w_rows, 0.0),
-        padk(alias_w_rows),
-        padk(thresh_d_rows, 0.0),
-        padk(alias_d_rows),
+        *rows,
         states.z,
         corpora.weights,
         j_prop,

@@ -61,25 +61,32 @@ def sweep_resample(
     # and a packed sweep consumes the *same* per-topic gumbel columns as
     # the exact sweep from the same key (the int4 lane over-padding only
     # adds -inf columns).
-    gumbel = jax.random.gumbel(key, (corpus.num_tokens, kp_base), jnp.float32)
-    # Padded topics get -inf scores via zero counts + -inf gumbel.
-    gumbel = jnp.where(jnp.arange(kp_base)[None, :] < k, gumbel, -jnp.inf)
-    if kp != kp_base:
-        gumbel = jnp.pad(gumbel, ((0, 0), (0, kp - kp_base)),
-                         constant_values=-jnp.inf)
+    with jax.named_scope("noise"):
+        gumbel = jax.random.gumbel(
+            key, (corpus.num_tokens, kp_base), jnp.float32)
+        # Padded topics get -inf scores via zero counts + -inf gumbel.
+        gumbel = jnp.where(
+            jnp.arange(kp_base)[None, :] < k, gumbel, -jnp.inf)
+        if kp != kp_base:
+            gumbel = jnp.pad(gumbel, ((0, 0), (0, kp - kp_base)),
+                             constant_values=-jnp.inf)
 
     if spec.packed:
         # Quantize the stale table once, gather packed rows per token.
         n_wt_real = codec.decode_array(cfg, state.n_wt)
         codes, scales = quant.quantize_rows_jnp(n_wt_real, spec.bits)
-        codes_rows = padk(codes[corpus.words])
-        if spec.bits == 4:
-            codes_rows = pack_halves(codes_rows)
+        with jax.named_scope("gather"):
+            codes_rows = padk(codes[corpus.words])
+            if spec.bits == 4:
+                codes_rows = pack_halves(codes_rows)
+            scale_rows = scales[corpus.words]
+            rows_d = padk(codec.decode_array(cfg, state.n_dt[corpus.docs]))
+            n_t = jnp.pad(codec.decode_array(cfg, state.n_t), (0, kp - k))
         return gibbs_resample_blocked_quant(
             codes_rows,
-            scales[corpus.words],
-            padk(codec.decode_array(cfg, state.n_dt[corpus.docs])),
-            jnp.pad(codec.decode_array(cfg, state.n_t), (0, kp - k)),
+            scale_rows,
+            rows_d,
+            n_t,
             state.z,
             corpus.weights,
             gumbel,
@@ -92,10 +99,14 @@ def sweep_resample(
 
     # Fixed-point counts are gathered *as int32* and rescaled inside the
     # kernel (saves the full (D,K)/(V,K) float materialization of from_fixed).
+    with jax.named_scope("gather"):
+        rows_d = padk(state.n_dt[corpus.docs])  # (N, K) gathers outside
+        rows_w = padk(state.n_wt[corpus.words])  # the kernel
+        n_t = jnp.pad(state.n_t, (0, kp - k))
     return gibbs_resample_blocked(
-        padk(state.n_dt[corpus.docs]),  # (N, K) gathers outside the kernel
-        padk(state.n_wt[corpus.words]),
-        jnp.pad(state.n_t, (0, kp - k)),
+        rows_d,
+        rows_w,
+        n_t,
         state.z,
         corpus.weights,
         gumbel,
@@ -140,24 +151,30 @@ def sweep_many(
     k = cfg.num_topics
     kp = -(-k // 128) * 128
 
-    rows_d = jax.vmap(lambda n_dt, d: n_dt[d])(states.n_dt, corpora.docs)
-    rows_w = jax.vmap(lambda n_wt, w: n_wt[w])(states.n_wt, corpora.words)
-
     def padk(x, fill=0):
         return jnp.pad(
             x, ((0, 0), (0, 0), (0, kp - k)), constant_values=fill
         )
 
-    gumbel = jax.vmap(
-        lambda kk: jax.random.gumbel(kk, (n, kp), jnp.float32)
-    )(keys)
-    # Padded topics get -inf scores via zero counts + -inf gumbel.
-    gumbel = jnp.where(jnp.arange(kp)[None, None, :] < k, gumbel, -jnp.inf)
+    with jax.named_scope("gather"):
+        rows_d = padk(
+            jax.vmap(lambda n_dt, d: n_dt[d])(states.n_dt, corpora.docs))
+        rows_w = padk(
+            jax.vmap(lambda n_wt, w: n_wt[w])(states.n_wt, corpora.words))
+        n_t = jnp.pad(states.n_t, ((0, 0), (0, kp - k)))
+
+    with jax.named_scope("noise"):
+        gumbel = jax.vmap(
+            lambda kk: jax.random.gumbel(kk, (n, kp), jnp.float32)
+        )(keys)
+        # Padded topics get -inf scores via zero counts + -inf gumbel.
+        gumbel = jnp.where(
+            jnp.arange(kp)[None, None, :] < k, gumbel, -jnp.inf)
 
     z_new = gibbs_resample_blocked_batched(
-        padk(rows_d),
-        padk(rows_w),
-        jnp.pad(states.n_t, ((0, 0), (0, kp - k))),
+        rows_d,
+        rows_w,
+        n_t,
         states.z,
         corpora.weights,
         gumbel,

@@ -13,23 +13,22 @@ Two problems with naive `time.time()` deltas in this codebase:
 `DeviceTimer` is also the bridge into the metrics registry: give it a
 `Histogram` and labels and the elapsed seconds are observed on stop.
 While `repro.obs.config` is disabled the timer skips the sync (preserving
-async dispatch — the zero-cost contract) and observes nothing.
-
-Optional `jax.profiler` integration: `annotate(name)` wraps a region in
-`jax.profiler.TraceAnnotation` when a profiler trace is being captured,
-and degrades to a no-op where the hook is unavailable.
+async dispatch — the zero-cost contract) and observes nothing. While
+enabled, the wait is a `device.wait` span (`repro.obs.trace`), so a
+profiler trace shows where the host blocked on the device — and an
+instrumented process syncs once per timed op where an uninstrumented one
+does not.
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Optional
 
-from repro.obs import config
+from repro.obs import config, trace
 from repro.obs.metrics import Histogram
 
-__all__ = ["now", "DeviceTimer", "annotate"]
+__all__ = ["now", "DeviceTimer"]
 
 
 def now() -> float:
@@ -85,7 +84,9 @@ class DeviceTimer:
         seconds (None when disabled or never started)."""
         if not config._enabled or self._t0 is None:
             return None
-        _block(value)
+        if value is not None:
+            with trace.span("device.wait"):
+                _block(value)
         self.elapsed_s = time.perf_counter() - self._t0
         self._t0 = None
         if self._hist is not None:
@@ -95,19 +96,3 @@ class DeviceTimer:
     def stop(self) -> Optional[float]:
         """Stop without waiting on a device value (host-side regions)."""
         return self.sync(None)
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Label a region for `jax.profiler` traces when one is being
-    captured; a no-op when obs is disabled or the hook is missing."""
-    if not config._enabled:
-        yield
-        return
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:
-        yield
-        return
-    with TraceAnnotation(name):
-        yield

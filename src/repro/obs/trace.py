@@ -24,7 +24,10 @@ duplicates (`tests/test_obs.py` asserts this across
 
 Finished spans land in a bounded process-wide buffer (oldest dropped),
 exportable as Chrome trace-event JSON (`chrome://tracing`, Perfetto) or
-JSONL. Everything is a no-op while `repro.obs.config` is disabled.
+JSONL. An enabled span is also a `jax.profiler.TraceAnnotation` of the
+same name, so a profiler trace shows it as a host event on the device
+trace's clock. Everything is a no-op while `repro.obs.config` is
+disabled: the span then reads the flag and yields, nothing more.
 """
 
 from __future__ import annotations
@@ -144,7 +147,10 @@ def span(name: str, **attrs):
     """Open a timed span; children opened inside share its trace id.
 
     Yields the live `Span` (mutate `attrs` or call `.set(...)` to attach
-    results) — or a no-op stand-in while obs is disabled.
+    results) — or a no-op stand-in while obs is disabled. While enabled
+    the region is also a profiler annotation named `name` (attributes stay
+    in the span record: an annotation's name is what a trace reader
+    matches on).
     """
     if not config._enabled:
         yield _NULL
@@ -159,8 +165,11 @@ def span(name: str, **attrs):
         attrs=dict(attrs),
     )
     token = _current.set(TraceContext(sp.trace_id, sp.span_id))
+    from jax.profiler import TraceAnnotation
+
     try:
-        yield sp
+        with TraceAnnotation(name):
+            yield sp
     finally:
         _current.reset(token)
         sp.duration_s = time.perf_counter() - sp.start_s

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 
 from repro.core import batch as batch_lib
 from repro.core.types import Corpus, LDAConfig, LDAState
-from repro.obs import metrics
+from repro.obs import metrics, trace
 
 #: Padding waste is the honest cost of the power-of-two shape ladder:
 #: every padded token slot runs the sweep like a real one. The pair of
@@ -134,16 +134,19 @@ def _run_bucket(
     _BUCKET_MODELS.observe(len(idxs))
     _REAL_TOKENS.inc(real_tokens)
     _PADDED_TOKENS.inc(len(idxs) * n_pad - real_tokens)
-    bcfg = batch_lib.batch_cfg(b_cfgs, d_pad)
-    stacked_c = batch_lib.stack_corpora(b_corps, n_pad)
-    stacked_s = None
-    if states is not None:
-        stacked_s = batch_lib.stack_states(
-            bcfg, b_cfgs, [states[i] for i in idxs], n_pad)
-    out = sampler.run_many(
-        bcfg, stacked_c, jnp.stack([keys[i] for i in idxs]), num_sweeps,
-        states=stacked_s)
-    return batch_lib.unstack_states(b_cfgs, b_corps, out)
+    with trace.span("batch.stack"):
+        bcfg = batch_lib.batch_cfg(b_cfgs, d_pad)
+        stacked_c = batch_lib.stack_corpora(b_corps, n_pad)
+        stacked_s = None
+        if states is not None:
+            stacked_s = batch_lib.stack_states(
+                bcfg, b_cfgs, [states[i] for i in idxs], n_pad)
+        stacked_k = jnp.stack([keys[i] for i in idxs])
+    with trace.span("batch.launch"):
+        out = sampler.run_many(
+            bcfg, stacked_c, stacked_k, num_sweeps, states=stacked_s)
+    with trace.span("batch.unstack"):
+        return batch_lib.unstack_states(b_cfgs, b_corps, out)
 
 
 def run_batched(
@@ -166,7 +169,9 @@ def run_batched(
         raise ValueError("cfgs, corpora and keys must align")
     if states is not None and len(states) != len(cfgs):
         raise ValueError("states must align with cfgs when given")
-    buckets = plan_buckets(list(zip(cfgs, corpora)), max_models=max_models)
+    with trace.span("batch.plan"):
+        buckets = plan_buckets(list(zip(cfgs, corpora)),
+                               max_models=max_models)
     out: list[Optional[LDAState]] = [None] * len(cfgs)
     for idxs in buckets:
         # vedalint: disable=prng-key-hygiene -- `keys` is the whole per-model

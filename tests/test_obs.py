@@ -187,6 +187,94 @@ def test_chrome_export_events():
     assert outer["dur"] >= 0
 
 
+def _host_event_names(log_dir) -> set:
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return {e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+def test_enabled_span_is_a_profiler_host_event(tmp_path):
+    """While obs is enabled a span is also a profiler annotation of the
+    same name, on the trace's host clock; while disabled it is not."""
+    import jax
+    import jax.numpy as jnp
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        obs.enable()
+        with trace.span("obs_test.on", k=1):
+            jnp.ones(8).block_until_ready()
+        obs.disable()
+        with trace.span("obs_test.off"):
+            jnp.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_event_names(str(tmp_path))
+    assert "obs_test.on" in names  # attributes stay off the event name
+    assert "obs_test.off" not in names
+    on, = trace.spans()  # the span record is unchanged by the annotation
+    assert on.name == "obs_test.on" and on.attrs == {"k": 1}
+
+
+def _span_tree() -> dict:
+    """name -> set of parent names, over the buffered spans."""
+    by_id = {s.span_id: s for s in trace.spans()}
+    out: dict = {}
+    for s in trace.spans():
+        parent = by_id.get(s.parent_id)
+        out.setdefault(s.name, set()).add(parent.name if parent else None)
+    return out
+
+
+def test_request_path_spans_name_each_layer():
+    """A coalesced refit over the wire opens one span per layer: wire,
+    service, batch engine, sampler, the device waits and the per-model
+    perplexities of the response, each of whose host reads is a wait."""
+    server = VedaliaServer(backend="batched", num_sweeps=1)
+    client = VedaliaClient(server=server)
+    fits = client.fit_batch([_reviews(seed=i) for i in range(3)],
+                            num_topics=4, base_vocab=120, w_bits=None)
+    obs.enable()
+    client.refine_batch([f.handle_id for f in fits], 1)
+    tree = _span_tree()
+    assert tree["server.refine_batch"] == {"client.refine_batch"}
+    assert tree["service.refine_many"] == {"server.refine_batch"}
+    for name in ("batch.plan", "batch.stack", "batch.launch",
+                 "batch.unstack"):
+        assert tree[name] == {"service.refine_many"}
+    assert tree["device.wait"] == {"service.refine_many",
+                                   "service.perplexity"}
+    assert tree["sampler.run_many"] == {"batch.launch"}
+    assert tree["service.perplexity"] == {"server.refine_batch"}
+    assert sum(s.name == "service.perplexity" for s in trace.spans()) == 3
+    by_id = {s.span_id: s for s in trace.spans()}
+    assert sum(s.name == "device.wait" and by_id[s.parent_id].name
+               == "service.perplexity" for s in trace.spans()) == 3
+
+    trace.reset()
+    client.refine(fits[0].handle_id, 1, backend="jnp")
+    tree = _span_tree()
+    assert tree["service.refine"] == {"server.refine"}
+    assert tree["device.wait"] == {"service.refine", "service.perplexity"}
+
+
+def test_device_timer_wait_is_a_span():
+    import jax.numpy as jnp
+
+    obs.enable()
+    timers.DeviceTimer().start().sync(None)  # nothing to wait for
+    assert trace.spans() == []
+    timers.DeviceTimer().start().sync(jnp.ones(4))
+    assert [s.name for s in trace.spans()] == ["device.wait"]
+
+
 # -- timers ------------------------------------------------------------------
 
 

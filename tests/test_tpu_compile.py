@@ -7,6 +7,12 @@ overflow VMEM. These tests lower and compile every main-path kernel
 variant against one chip of a described `v5e:2x2` topology, at K=128 and
 K=1024 with N padded from a 300k-token corpus. Nothing runs: shapes only.
 
+The sampler programs name their phases with `jax.named_scope` (gather,
+noise, alias tables, count rebuild, perplexity), which a profiler trace
+reads back from each operation's metadata: the last tests check that each
+program names its phases, and that the scopes change the compiled program
+in its metadata alone.
+
 The topology is described inside a module fixture (never at import), so
 every test worker collects the same tests and only the worker that runs
 this file loads the TPU compiler.
@@ -14,16 +20,23 @@ this file loads the TPU compiler.
 
 from __future__ import annotations
 
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core import perplexity
+from repro.core.quant import QuantSpec
+from repro.core.types import Corpus, LDAConfig, LDAState
+from repro.kernels.alias_mh import ops as alias_ops
 from repro.kernels.alias_mh.kernel import (
     alias_mh_blocked,
     alias_mh_blocked_batched,
 )
+from repro.kernels.lda_gibbs import ops as gibbs_ops
 from repro.kernels.lda_gibbs.kernel import (
     gibbs_resample_blocked,
     gibbs_resample_blocked_batched,
@@ -136,3 +149,91 @@ def test_alias_mh_batched_compiles(one_chip, k):
         return alias_mh_blocked_batched(*args, **HYPER, interpret=False)
 
     _compile(fn, one_chip, *_alias_shapes((M_MODELS[k],), N_PER_MODEL, k))
+
+
+# -- named phases -------------------------------------------------------------
+
+PHASES = ("gather", "noise", "alias_tables", "count_rebuild", "perplexity")
+SAMPLER = ("gather", "noise", "count_rebuild")
+#: A tiny coalesced batch: (models, tokens, topics, vocabulary, documents).
+TINY = (2, 2048, 12, 256, 32)
+
+
+def _lower(name, sharding=None):
+    m, n, k, v, d = TINY
+    # `mh_sweep_int8` is `mh_sweep` with int8 word-topic rows: the packed
+    # branch of `mh_resample`.
+    quant = QuantSpec.int8() if name.endswith("_int8") else None
+    cfg = LDAConfig(num_topics=k, vocab_size=v, num_docs=d, quant=quant)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def args(*lead):
+        state = LDAState(
+            z=spec((*lead, n), I32), n_dt=spec((*lead, d, k), F32),
+            n_wt=spec((*lead, v, k), F32), n_t=spec((*lead, k), F32))
+        corpus = Corpus(spec((*lead, n), I32), spec((*lead, n), I32),
+                        spec((*lead, n), F32))
+        return state, corpus, spec((*lead, 2), jnp.uint32)
+
+    if name == "log_likelihood":
+        return perplexity.log_likelihood.lower(cfg, *args()[:2])
+    if name == "sweep_resample":
+        return gibbs_ops.sweep_resample.lower(cfg, *args())
+    if name == "sweep_many":
+        return gibbs_ops.sweep_many.lower(cfg, *args(m))
+    if name in ("mh_sweep", "mh_sweep_int8"):
+        return alias_ops.mh_sweep.lower(cfg, *args())
+    return alias_ops.mh_sweep_many.lower(cfg, *args(m))
+
+
+PROGRAMS = {"sweep_resample": {"gather", "noise"},  # returns z: no rebuild
+            "sweep_many": set(SAMPLER),
+            "mh_sweep": {*SAMPLER, "alias_tables"},
+            "mh_sweep_int8": {*SAMPLER, "alias_tables"},
+            "mh_sweep_many": {*SAMPLER, "alias_tables"},
+            "log_likelihood": {"perplexity"}}
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_sampler_program_names_its_phases(name):
+    """Each phase scope reaches the lowered program's locations (a scope
+    under `vmap` reads `vmap(<scope>)`), so a refactor cannot silently
+    empty a phase of the trace."""
+    text = _lower(name).as_text(debug_info=True)
+    named = {p for p in PHASES if re.search(rf'[/("]{p}[/)]', text)}
+    assert named == PROGRAMS[name]
+
+
+@contextlib.contextmanager
+def _no_scope(_name):
+    yield
+
+
+def _compiled_text(name, sharding, scoped):
+    real = jax.named_scope
+    if not scoped:
+        jax.named_scope = _no_scope
+    try:
+        jax.clear_caches()  # retrace: the jaxpr cache ignores the scopes
+        return _lower(name, sharding).compile().as_text()
+    finally:
+        jax.named_scope = real
+
+
+def _strip_metadata(text):
+    return re.sub(r", metadata=\{[^}]*\}", "", text)
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_phase_scopes_change_only_metadata(one_chip, monkeypatch, name):
+    """The chip's optimised program (Pallas kernels through Mosaic) is the
+    same with and without the phase scopes once metadata is stripped. Both
+    compiles run from one call site: the metadata also records the Python
+    stack that traced each operation."""
+    monkeypatch.setattr(gibbs_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(alias_ops, "_interpret", lambda: False)
+    scoped, bare = [_compiled_text(name, one_chip, s) for s in (True, False)]
+    assert scoped != bare  # the scopes are in the metadata
+    assert _strip_metadata(scoped) == _strip_metadata(bare)
