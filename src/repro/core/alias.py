@@ -18,8 +18,9 @@ sweep-parallel.
 Alias-table construction is an exact linearization of Vose's algorithm
 (`build_alias_tables`): sort each row into light/heavy buckets, take prefix
 sums, and read every threshold and alias off the cumulative deficit/excess
-curves — O(K log K) work at O(log K) parallel depth per row, vectorized
-across the whole (V, K) table at once. The fused Pallas sweep lives in
+curves by counting comparisons against them — O(K^2) lane-parallel work at
+O(log K) depth per row, no per-element gather or scatter, vectorized over
+blocks of rows of the (V, K) table. The fused Pallas sweep lives in
 `repro.kernels.alias_mh`; this module is the jnp system path and the parity
 oracle.
 """
@@ -30,8 +31,35 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.types import Corpus, LDAConfig, LDAState, build_counts
+
+
+#: Elements one row block's (rows, K, K+1) comparisons may span.
+_BLOCK_ELEMS = 2**26
+
+
+def _row_block(k: int) -> int:
+    """Rows built together: the largest power of two whose row-wise
+    (rows, K, K+1) comparisons stay within `_BLOCK_ELEMS` (512 rows at
+    K=256, 32 at K=1024), so the temporaries shrink as K grows."""
+    return 1 << max(0, (_BLOCK_ELEMS // (k * (k + 1))).bit_length() - 1)
+
+
+def _count(hits: jax.Array) -> jax.Array:
+    """Per query (lane), how many of the curve's entries (rows of `hits`)
+    pass the comparison: `searchsorted`'s position. Plain comparisons order
+    these NaN-free, non-negative curves as `searchsorted`'s do."""
+    return jnp.sum(hits, axis=0, dtype=jnp.int32)
+
+
+def _pick(values: jax.Array, idx: jax.Array) -> jax.Array:
+    """`values[idx]` for a row, as a one-hot select summed over the row:
+    every term but one is zero, so the sum is the value itself."""
+    hit = jnp.arange(values.shape[0], dtype=idx.dtype)[:, None] == idx
+    return jnp.sum(jnp.where(hit, values[:, None], 0), axis=0,
+                   dtype=values.dtype)
 
 
 def _build_row(mass: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -50,15 +78,24 @@ def _build_row(mass: jax.Array) -> tuple[jax.Array, jax.Array]:
                 where i(j) = first light with D_i >= E_j (the light whose
                 fill drains donor j below 1; D_0 = 0).
 
+    No lookup indexes the row per element. The partition and its inverse
+    are stable sorts that carry each bucket's topic, mass, threshold and
+    alias along. Each search counts the curve's entries on one side of the
+    query, the position `searchsorted` returns, as a masked sum over the
+    row; each read at a searched position is a one-hot select (`_pick`).
+    Both reduce over the row's buckets with one query per lane. A row costs
+    O(K^2) comparisons, which the chip runs as lane-parallel reductions,
+    where a gather costs a memory access per element.
+
     Mass conservation per topic is exact by construction: a heavy topic t
     recovers its excess from the lights it fills plus the chain slice it
     receives from its predecessor.
     """
     k = mass.shape[0]
-    light = mass < 1.0
-    order = jnp.argsort(jnp.where(light, 0, 1))  # lights first (stable)
-    m_s = mass[order]
-    light_s = light[order]
+    pos = jnp.arange(k, dtype=jnp.int32)
+    _, order, m_s = lax.sort(  # lights first (stable)
+        (jnp.where(mass < 1.0, 0, 1), pos, mass), num_keys=1)
+    light_s = m_s < 1.0
 
     deficit = jnp.where(light_s, 1.0 - m_s, 0.0)
     excess = jnp.where(light_s, 0.0, m_s - 1.0)
@@ -68,23 +105,19 @@ def _build_row(mass: jax.Array) -> tuple[jax.Array, jax.Array]:
     # Lights: the open donor when light i arrives is the first heavy whose
     # cumulative excess exceeds the deficit already absorbed (D_{i-1}).
     d_prev = cum_d - deficit
-    donor = jnp.clip(
-        jnp.searchsorted(cum_e, d_prev, side="right"), 0, k - 1)
+    donor = jnp.minimum(_count(cum_e[:, None] <= d_prev), k - 1)
 
     # Heavies: donor j is drained by the first light whose cumulative
     # deficit reaches E_j; its residual at that point is the threshold.
     cum_d_ext = jnp.concatenate([jnp.zeros(1, cum_d.dtype), cum_d])
-    closer = jnp.clip(
-        jnp.searchsorted(cum_d_ext, cum_e, side="left"), 0, k)
-    thresh_heavy = jnp.clip(1.0 + cum_e - cum_d_ext[closer], 0.0, 1.0)
+    closer = jnp.minimum(_count(cum_d_ext[:, None] < cum_e), k)
+    thresh_heavy = jnp.clip(1.0 + cum_e - _pick(cum_d_ext, closer), 0.0, 1.0)
 
-    pos = jnp.arange(k, dtype=jnp.int32)
     thresh_s = jnp.where(light_s, m_s, thresh_heavy)
     alias_pos = jnp.where(light_s, donor, jnp.minimum(pos + 1, k - 1))
-    alias_s = order[alias_pos].astype(jnp.int32)
+    alias_s = _pick(order, alias_pos)
 
-    thresh = jnp.zeros_like(m_s).at[order].set(thresh_s)
-    alias = jnp.zeros(k, jnp.int32).at[order].set(alias_s)
+    _, thresh, alias = lax.sort((order, thresh_s, alias_s), num_keys=1)
     return thresh, alias
 
 
@@ -95,11 +128,11 @@ def build_alias_tables(probs: jax.Array) -> tuple[jax.Array, jax.Array]:
     `(thresh, alias)` of the same batch shape. Sample u~U[0,1),
     j~U{0..K-1}; topic = j if u < thresh[..., j] else alias[..., j].
 
-    Construction is branch-free sort + prefix sums (see `_build_row`):
-    O(K log K) work and O(log K) parallel depth per row, with every row of
-    a (V, K) table built in one vectorized pass — this replaces the
-    K-step sequential pairing scan that made table rebuilds the serial
-    bottleneck of the alias sweep.
+    Construction is branch-free sorts, prefix sums and row reductions
+    (see `_build_row`), with no per-element gather or scatter. Rows are
+    built in blocks of `_row_block(K)`, each block vectorized, so the
+    (rows, K, K+1) comparisons stay a bounded temporary at any table
+    height.
 
     Rows whose total mass is zero (a word never observed, all counts
     flushed) fall back to an explicit uniform distribution rather than
@@ -113,7 +146,7 @@ def build_alias_tables(probs: jax.Array) -> tuple[jax.Array, jax.Array]:
         ok = row_sum > 0.0
         mass = jnp.where(ok, probs * (k / jnp.where(ok, row_sum, 1.0)), 1.0)
         flat = mass.reshape((-1, k))
-        thresh, alias = jax.vmap(_build_row)(flat)
+        thresh, alias = lax.map(_build_row, flat, batch_size=_row_block(k))
         return thresh.reshape(lead + (k,)), alias.reshape(lead + (k,))
 
 

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import alias, gibbs, perplexity
 from repro.core.sparse import DenseGibbsSampler, SparseLDASampler
@@ -183,13 +184,95 @@ def test_alias_tables_batched_matches_per_row():
         np.testing.assert_array_equal(np.asarray(al[i]), np.asarray(a_i))
 
 
+def _oracle_build_row(mass):
+    """The gather-based row builder the blocked one replaced (binary
+    searches, per-element gathers, a scatter to un-permute), kept as the
+    bit-exact oracle."""
+    k = mass.shape[0]
+    light = mass < 1.0
+    order = jnp.argsort(jnp.where(light, 0, 1))  # lights first (stable)
+    m_s = mass[order]
+    light_s = light[order]
+
+    deficit = jnp.where(light_s, 1.0 - m_s, 0.0)
+    excess = jnp.where(light_s, 0.0, m_s - 1.0)
+    cum_d = jnp.cumsum(deficit)  # constant on the heavy suffix
+    cum_e = jnp.cumsum(excess)  # zero on the light prefix
+
+    # Lights: the open donor when light i arrives is the first heavy whose
+    # cumulative excess exceeds the deficit already absorbed (D_{i-1}).
+    d_prev = cum_d - deficit
+    donor = jnp.clip(
+        jnp.searchsorted(cum_e, d_prev, side="right"), 0, k - 1)
+
+    # Heavies: donor j is drained by the first light whose cumulative
+    # deficit reaches E_j; its residual at that point is the threshold.
+    cum_d_ext = jnp.concatenate([jnp.zeros(1, cum_d.dtype), cum_d])
+    closer = jnp.clip(
+        jnp.searchsorted(cum_d_ext, cum_e, side="left"), 0, k)
+    thresh_heavy = jnp.clip(1.0 + cum_e - cum_d_ext[closer], 0.0, 1.0)
+
+    pos = jnp.arange(k, dtype=jnp.int32)
+    thresh_s = jnp.where(light_s, m_s, thresh_heavy)
+    alias_pos = jnp.where(light_s, donor, jnp.minimum(pos + 1, k - 1))
+    alias_s = order[alias_pos].astype(jnp.int32)
+
+    thresh = jnp.zeros_like(m_s).at[order].set(thresh_s)
+    alias = jnp.zeros(k, jnp.int32).at[order].set(alias_s)
+    return thresh, alias
+
+
+def _oracle_build_alias_tables(probs):
+    with jax.named_scope("alias_tables"):
+        probs = jnp.asarray(probs, jnp.float32)
+        k = probs.shape[-1]
+        lead = probs.shape[:-1]
+        row_sum = probs.sum(-1, keepdims=True)
+        ok = row_sum > 0.0
+        mass = jnp.where(ok, probs * (k / jnp.where(ok, row_sum, 1.0)), 1.0)
+        flat = mass.reshape((-1, k))
+        thresh, alias = jax.vmap(_oracle_build_row)(flat)
+        return thresh.reshape(lead + (k,)), alias.reshape(lead + (k,))
+
+
+def _parity_rows(k, seed):
+    """Rows that stress the builder: integer counts + beta (ties), sparse
+    Dirichlet rows, all-zero, one-hot and exactly uniform rows, and the
+    drained-donor chain (one near-empty bucket)."""
+    rng = np.random.default_rng(seed)
+    chain = np.r_[1e-7, np.full(k - 1, (1 - 1e-7) / (k - 1))]
+    return np.concatenate([
+        rng.integers(0, 4, (24, k)) + 0.01,
+        rng.dirichlet(np.full(k, 0.05), 8),
+        np.zeros((3, k)),
+        np.eye(k)[rng.permutation(k)[:4]],
+        np.full((2, k), 1.0 / k), np.full((1, k), 3.0),
+        chain[None], chain[::-1][None],
+    ]).astype(np.float32)
+
+
+@pytest.mark.parametrize("blocked", [False, True], ids=["default", "16_rows"])
+@pytest.mark.parametrize("k", [2, 12, 128, 256, 1024])
+def test_alias_tables_bit_identical_to_gather_builder(monkeypatch, k, blocked):
+    """The gather-free blocked builder returns the gather-based builder's
+    thresholds and aliases bit for bit. With 16-row blocks the 42-44 rows
+    leave a remainder, so the scanned blocks and the remainder both run;
+    at K=1024 the default block (32 rows) does the same."""
+    if blocked:
+        monkeypatch.setattr(alias, "_BLOCK_ELEMS", 16 * k * (k + 1))
+    probs = jnp.asarray(_parity_rows(k, seed=k))
+    want_t, want_a = jax.jit(_oracle_build_alias_tables)(probs)
+    got_t, got_a = alias.build_alias_tables(probs)
+    np.testing.assert_array_equal(
+        np.asarray(got_t).view(np.int32), np.asarray(want_t).view(np.int32))
+    np.testing.assert_array_equal(np.asarray(got_a), np.asarray(want_a))
+
+
 def test_sweep_checkify_clean():
     """Sanitized leg (REPRO_SANITIZE=1): a full sweep is clean under
     checkify's float + index checks — no NaNs, no div-by-zero, and every
     count-table gather/scatter in bounds."""
     import os
-
-    import pytest
 
     if os.environ.get("REPRO_SANITIZE") != "1":
         pytest.skip("sanitized leg only (set REPRO_SANITIZE=1)")

@@ -5,7 +5,8 @@ see what Mosaic refuses: operand layouts XLA and Mosaic tile differently,
 blocks that break the (8, 128) rule, casts the chip lacks, tiles that
 overflow VMEM. These tests lower and compile every main-path kernel
 variant against one chip of a described `v5e:2x2` topology, at K=128 and
-K=1024 with N padded from a 300k-token corpus. Nothing runs: shapes only.
+K=1024 with N padded from a 300k-token corpus, and the alias-table build
+at the NYTimes vocabulary. Nothing runs: shapes only.
 
 The sampler programs name their phases with `jax.named_scope` (gather,
 noise, alias tables, count rebuild, perplexity), which a profiler trace
@@ -28,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core import alias as alias_core
 from repro.core import perplexity
 from repro.core.quant import QuantSpec
 from repro.core.types import Corpus, LDAConfig, LDAState
@@ -149,6 +151,24 @@ def test_alias_mh_batched_compiles(one_chip, k):
         return alias_mh_blocked_batched(*args, **HYPER, interpret=False)
 
     _compile(fn, one_chip, *_alias_shapes((M_MODELS[k],), N_PER_MODEL, k))
+
+
+#: The gather-based table builder's temporaries on one v5e at the NYTimes
+#: vocabulary (102,660 words): the blocked builder must stay below them.
+ALIAS_TABLE_TEMP_BYTES = {256: 1.00e9, 1024: 3.68e9}
+
+
+@pytest.mark.parametrize("k", sorted(ALIAS_TABLE_TEMP_BYTES))
+def test_alias_tables_compile_without_gathers(one_chip, k):
+    """The alias-table build has no per-element gather or scatter on the
+    chip (each cost a memory access per entry of the V x K table), and
+    its row blocks keep its temporaries below the gather builder's."""
+    arg = jax.ShapeDtypeStruct((102_660, k), F32, sharding=one_chip)
+    compiled = jax.jit(alias_core.build_alias_tables).lower(arg).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\b(gather|scatter)\(", text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= ALIAS_TABLE_TEMP_BYTES[k], temp
 
 
 # -- named phases -------------------------------------------------------------
