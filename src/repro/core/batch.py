@@ -14,7 +14,10 @@ model axis and swept together:
   * the fused path lives in `repro.kernels.lda_gibbs.ops.sweep_many`
     (model-grid Pallas kernel) and is selected by the `batched` registry
     backend (`repro.api.backends.BatchedSampler`);
-  * stacking/unstacking and padding helpers shared by both paths.
+  * `stack_bucket` / `unstack_states`, shared by both paths: a bucket is
+    stacked by one jitted padding program per model and one jitted stack,
+    and unstacked by one jitted slice per model of the counts the last
+    sweep already rebuilt.
 
 Stacked pytrees reuse `Corpus` and `LDAState` verbatim with a leading
 (M,) axis on every leaf — `jax.vmap` and the kernel BlockSpecs both
@@ -68,45 +71,87 @@ def pad_corpus(corpus: Corpus, num_tokens: int) -> Corpus:
             f"corpus has {corpus.num_tokens} tokens > pad target {num_tokens}")
     if pad == 0:
         return corpus
-    return Corpus(
-        docs=jnp.pad(corpus.docs, (0, pad)),
-        words=jnp.pad(corpus.words, (0, pad)),
-        weights=jnp.pad(corpus.weights, (0, pad)),
-    )
+    return _pad_corpus(corpus, num_tokens)
 
 
-def stack_corpora(corpora: Sequence[Corpus], num_tokens: int) -> Corpus:
-    """Stack corpora into one (M, num_tokens) batch (weight-0 padding)."""
-    padded = [pad_corpus(c, num_tokens) for c in corpora]
-    return Corpus(
-        docs=jnp.stack([c.docs for c in padded]),
-        words=jnp.stack([c.words for c in padded]),
-        weights=jnp.stack([c.weights for c in padded]),
-    )
+def _pad_tokens(x: jax.Array, num_tokens: int) -> jax.Array:
+    return jnp.pad(x, (0, num_tokens - x.shape[0]))
 
 
-def stack_states(
-    bcfg: LDAConfig,
-    cfgs: Sequence[LDAConfig],
-    states: Sequence[LDAState],
+def _pad_corpus(corpus: Corpus, num_tokens: int) -> Corpus:
+    return Corpus(*(_pad_tokens(x, num_tokens)
+                    for x in (corpus.docs, corpus.words, corpus.weights)))
+
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _pad_model(
+    corpus: Corpus,
+    state: Optional[LDAState],
     num_tokens: int,
-) -> LDAState:
-    """Stack warm per-model states (stored units) to the batch shape.
+    num_docs: int,
+) -> tuple[Corpus, Optional[LDAState]]:
+    """Every leaf of one model's corpus and warm state (or None) padded to
+    the bucket's `(num_tokens, num_docs)` shape in one program.
 
-    z pads with topic 0 (padding tokens have weight 0 and keep their
-    assignment), n_dt pads with zero rows up to the document capacity.
+    Corpus padding is weight-0 (doc/word id 0); z pads with topic 0 and
+    n_dt with zero rows, so no padded slot enters any count.
     """
-    zs, n_dts = [], []
-    for cfg, st in zip(cfgs, states):
-        zs.append(jnp.pad(st.z, (0, num_tokens - st.z.shape[0])))
-        n_dts.append(jnp.pad(
-            st.n_dt, ((0, bcfg.num_docs - cfg.num_docs), (0, 0))))
-    return LDAState(
-        z=jnp.stack(zs),
-        n_dt=jnp.stack(n_dts),
-        n_wt=jnp.stack([st.n_wt for st in states]),
-        n_t=jnp.stack([st.n_t for st in states]),
-    )
+    corpus = _pad_corpus(corpus, num_tokens)
+    if state is not None:
+        state = LDAState(
+            z=_pad_tokens(state.z, num_tokens),
+            n_dt=jnp.pad(
+                state.n_dt, ((0, num_docs - state.n_dt.shape[0]), (0, 0))),
+            n_wt=state.n_wt,
+            n_t=state.n_t,
+        )
+    return corpus, state
+
+
+@jax.jit
+def _stack(models, keys):
+    """M padded models of one shape and their keys stacked along a leading
+    model axis: one program per `(M, num_tokens, num_docs)`."""
+    return (jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *models),
+            jnp.stack(keys))
+
+
+def stack_bucket(
+    corpora: Sequence[Corpus],
+    keys: Sequence[jax.Array],
+    num_tokens: int,
+    num_docs: int,
+    states: Optional[Sequence[LDAState]] = None,
+) -> tuple[Corpus, Optional[LDAState], jax.Array]:
+    """Stack M models into one `(M, num_tokens)` batch with `num_docs`
+    document rows: one padding program per model, one stacking program per
+    bucket. Cold buckets (`states=None`) stack the corpora and keys alone.
+
+    Compile keys name a model's own shape, the padded shape and M, never
+    which models a bucket holds, so any subset of served models reuses the
+    programs compiled for its shapes.
+    """
+    for c in corpora:
+        if c.num_tokens > num_tokens:
+            raise ValueError(
+                f"corpus has {c.num_tokens} tokens > pad target {num_tokens}")
+    if states is None:
+        states = [None] * len(corpora)
+    padded = [_pad_model(c, s, num_tokens, num_docs)
+              for c, s in zip(corpora, states)]
+    (corpus, state), stacked_keys = _stack(padded, list(keys))
+    return corpus, state, stacked_keys
+
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _take_model(
+    states: LDAState, i: jax.Array, num_tokens: int, num_docs: int
+) -> LDAState:
+    """Model `i` of a stacked state, trimmed to `(num_tokens, num_docs)`."""
+    st = jax.tree_util.tree_map(
+        lambda x: jax.lax.dynamic_index_in_dim(x, i, keepdims=False), states)
+    return LDAState(z=st.z[:num_tokens], n_dt=st.n_dt[:num_docs],
+                    n_wt=st.n_wt, n_t=st.n_t)
 
 
 def unstack_states(
@@ -114,11 +159,17 @@ def unstack_states(
     corpora: Sequence[Corpus],
     states: LDAState,
 ) -> list[LDAState]:
-    """Trim each model's z back to its true token count and rebuild its
-    counts under its own (unpadded) config — stored units, same contract
-    as every single-model backend."""
+    """Model i's slice of a stacked `run_many` result, trimmed to its own
+    token count and document rows: one program per model, `i` traced.
+
+    No count is rebuilt. Every `run_many` ends its last sweep in a rebuild
+    and encode under the stack's config, whose storage format is each
+    model's; padding tokens weigh 0 and padded document rows stay zero, so
+    the slice is the model's stored state up to the order of float
+    additions.
+    """
     return [
-        codec.rebuild_state(cfg, corpus, states.z[i, : corpus.num_tokens])
+        _take_model(states, i, corpus.num_tokens, cfg.num_docs)
         for i, (cfg, corpus) in enumerate(zip(cfgs, corpora))
     ]
 

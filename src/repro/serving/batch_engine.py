@@ -8,6 +8,16 @@ drives the `batched` sampler backend (`repro.api.backends.BatchedSampler`
 over `core.batch` / the model-grid Pallas kernel), and unstacks the
 results back into ordinary per-model states.
 
+A bucket costs a few jitted dispatches besides its launch
+(`core.batch.stack_bucket`, `core.batch.unstack_states`): one program per
+model pads all of its corpus and state leaves to the bucket's shape, one
+program stacks them with the keys, and one program per model slices its
+state out of the launch's result. The counts the slice returns are those
+the launch's last sweep rebuilt; nothing is rebuilt again. Every compile
+key names a model's own shape, the bucket's padded shape or the number of
+models, never which models a bucket holds, so the arbitrary subsets that
+`refine_many` and the stream scheduler coalesce reuse compiled programs.
+
 Bucketing rules (a bucket = one launch):
 
   * hard compatibility — `core.batch.compat_key`: num_topics, vocab_size,
@@ -33,7 +43,6 @@ import dataclasses
 from typing import Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 
 from repro.core import batch as batch_lib
 from repro.core.types import Corpus, LDAConfig, LDAState
@@ -136,12 +145,9 @@ def _run_bucket(
     _PADDED_TOKENS.inc(len(idxs) * n_pad - real_tokens)
     with trace.span("batch.stack"):
         bcfg = batch_lib.batch_cfg(b_cfgs, d_pad)
-        stacked_c = batch_lib.stack_corpora(b_corps, n_pad)
-        stacked_s = None
-        if states is not None:
-            stacked_s = batch_lib.stack_states(
-                bcfg, b_cfgs, [states[i] for i in idxs], n_pad)
-        stacked_k = jnp.stack([keys[i] for i in idxs])
+        stacked_c, stacked_s, stacked_k = batch_lib.stack_bucket(
+            b_corps, [keys[i] for i in idxs], n_pad, d_pad,
+            states=None if states is None else [states[i] for i in idxs])
     with trace.span("batch.launch"):
         out = sampler.run_many(
             bcfg, stacked_c, stacked_k, num_sweeps, states=stacked_s)

@@ -130,8 +130,9 @@ def test_sweep_batch_is_exactly_m_single_model_sweeps(w_bits):
     n_pad = max(p.corpus.num_tokens for p in preps)
     bcfg = batch_lib.batch_cfg(
         cfgs, batch_engine.doc_bucket(max(c.num_docs for c in cfgs)))
-    stacked = batch_lib.stack_corpora([p.corpus for p in preps], n_pad)
     keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    stacked, _, _ = batch_lib.stack_bucket(
+        [p.corpus for p in preps], list(keys), n_pad, bcfg.num_docs)
     states = batch_lib.init_many(bcfg, stacked, keys)
 
     out = batch_lib.sweep_batch(bcfg, states, stacked, keys)
@@ -144,18 +145,118 @@ def test_sweep_batch_is_exactly_m_single_model_sweeps(w_bits):
             np.asarray(out.z[i, :n]), np.asarray(ref.z[:n]))
 
 
-def test_unstack_states_trims_and_rebuilds_per_model():
-    preps = _preps(2, w_bits=8)
+def _sized_review_sets(sizes):
+    """One review set per `(num_reviews, seed)`, so token and document
+    counts differ from set to set."""
+    return [
+        reviews_data.generate(reviews_data.SyntheticSpec(
+            num_reviews=n, vocab_size=200, num_topics=4, mean_tokens=25,
+            num_users=30, seed=seed)).reviews
+        for n, seed in sizes
+    ]
+
+
+class _RecordingSampler:
+    """Keeps every stacked `run_many` result, and forbids any count rebuild
+    between launches (that is, while a bucket unstacks)."""
+
+    def __init__(self, inner, monkeypatch):
+        self.inner, self.outs, self._mp = inner, [], monkeypatch
+        self._real = (codec.rebuild_state, codec.StateCodec.rebuild_state,
+                      codec.build_counts)
+
+    def _patch(self, fns):
+        self._mp.setattr(codec, "rebuild_state", fns[0])
+        self._mp.setattr(codec.StateCodec, "rebuild_state", fns[1])
+        self._mp.setattr(codec, "build_counts", fns[2])
+
+    def run_many(self, *args, **kw):
+        self._patch(self._real)
+        out = self.inner.run_many(*args, **kw)
+        self.outs.append(out)
+
+        def boom(*_a, **_k):
+            raise AssertionError("unstacking rebuilt counts")
+
+        self._patch((boom, boom, boom))
+        return out
+
+
+@pytest.mark.parametrize("path", ["jnp", "pallas"])
+@pytest.mark.parametrize("w_bits", [None, 8])
+def test_unstack_states_trims_and_rebuilds_per_model(w_bits, path,
+                                                     monkeypatch):
+    """Unstacking slices the launch's own rebuilt counts: z is the stacked
+    z trimmed, the counts are the model's rebuild (one stored unit for
+    fixed point, 1e-5 relative for float32), and no rebuild runs."""
+    preps = [rlda.prepare(rs, base_vocab=200, num_topics=6, w_bits=w_bits)
+             for rs in _sized_review_sets([(4, 50), (10, 51), (14, 52),
+                                           (12, 53)])]
     cfgs = [p.cfg for p in preps]
     corpora = [p.corpus for p in preps]
-    keys = [jax.random.PRNGKey(i) for i in range(2)]
+    keys = [jax.random.PRNGKey(i) for i in range(len(preps))]
+    buckets = batch_engine.plan_buckets(list(zip(cfgs, corpora)))
+    assert len(buckets) >= 2
+    assert len({c.num_tokens for c in corpora}) == len(corpora)
+    assert len({c.num_docs for c in cfgs}) == len(cfgs)
+
+    sampler = _RecordingSampler(get_backend("batched", path=path),
+                                monkeypatch)
+    cold, _ = batch_engine.run_batched(sampler, cfgs, corpora, keys, 1)
     states, stats = batch_engine.run_batched(
-        get_backend("batched", path="jnp"), cfgs, corpora, keys, 3)
-    assert stats.num_models == 2 and stats.num_launches >= 1
-    for cfg, corpus, st in zip(cfgs, corpora, states):
-        assert st.z.shape == (corpus.num_tokens,)
-        assert st.n_dt.shape == (cfg.num_docs, cfg.num_topics)
-        _assert_count_invariants(cfg, corpus, st)
+        sampler, cfgs, corpora, keys, 2, states=cold)
+    monkeypatch.undo()
+    assert stats.num_models == len(preps)
+    assert stats.num_launches == len(buckets)
+
+    warm_outs = sampler.outs[len(buckets):]
+    for idxs, out in zip(buckets, warm_outs):
+        for i in idxs:
+            cfg, corpus, st = cfgs[i], corpora[i], states[i]
+            n = corpus.num_tokens
+            assert st.z.shape == (n,)
+            assert st.n_dt.shape == (cfg.num_docs, cfg.num_topics)
+            np.testing.assert_array_equal(
+                np.asarray(st.z), np.asarray(out.z[idxs.index(i), :n]))
+            ref = codec.rebuild_state(cfg, corpus, st.z)
+            for got, want in ((st.n_dt, ref.n_dt), (st.n_wt, ref.n_wt),
+                              (st.n_t, ref.n_t)):
+                assert got.dtype == want.dtype and got.shape == want.shape
+                if w_bits is None:
+                    np.testing.assert_allclose(
+                        np.asarray(got), np.asarray(want), rtol=1e-5)
+                else:
+                    assert np.abs(np.asarray(got, np.int64)
+                                  - np.asarray(want, np.int64)).max() <= 1
+            _assert_count_invariants(cfg, corpus, st)
+
+
+def test_refine_many_new_subset_of_known_shapes_compiles_nothing():
+    """Compile keys never name a bucket's composition: once refine_many has
+    run over handles of three shapes, another subset in another order of
+    the same shapes (same bucket shape, same M) compiles no program."""
+    sets = _sized_review_sets([(4, 50), (6, 51), (10, 52)])
+    svc = VedaliaService(backend="auto", num_sweeps=2)
+    handles = svc.fit_batch(sets + sets, num_topics=6, base_vocab=200)
+    assert len({h.model.corpus.num_tokens for h in handles}) == 3
+    assert len(batch_engine.plan_buckets(
+        [(h.cfg, h.model.corpus) for h in handles])) == 1
+    svc.refine_many(handles[:3], 2)
+
+    compiles = []
+
+    def on(event: str, duration: float, **_kw) -> None:
+        if event.startswith("/jax/core/compile/"):
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        out = svc.refine_many([handles[5], handles[3], handles[1]], 2)
+        jax.block_until_ready([h.state for h in out])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    assert compiles == []
+    assert [h.sweeps_run for h in handles] == [4, 6, 4, 4, 2, 4]
 
 
 # -- service-level parity (the PR acceptance test) ---------------------------
